@@ -175,6 +175,7 @@ class ResidencyEngine:
         self.io_errors_detected = 0
         self.evict_dropped = 0
         self.recover_failed = 0
+        self.pipelined_restores = 0         # Fig. 8 scans that completed
         swapper.on_job_error = self._on_io_error
 
     # ------------------------------------------------------------------ #
@@ -260,6 +261,7 @@ class ResidencyEngine:
             "io_errors_detected": self.io_errors_detected,
             "evict_dropped": self.evict_dropped,
             "recover_failed": self.recover_failed,
+            "pipelined_restores": self.pipelined_restores,
             "io_retries": self.swapper.io_retries,
             "io_recovered": self.swapper.io_recovered,
             "io_failed_jobs": self.swapper.io_failed,
@@ -646,7 +648,7 @@ class ResidencyEngine:
                 did_recompute = True
             except SwapTimeoutError:
                 raise
-            except Exception as err:
+            except (ChunkCorruptError, OSError) as err:
                 # passed header validation but failed mid-feed (e.g. a
                 # flipped byte inside a layer segment): fall back to
                 # whole-file reads, which verify per-layer CRCs up front
@@ -751,10 +753,17 @@ class ResidencyEngine:
             out = exe.run_pipelined(feed, toks_b, miss_b, io_pos_b,
                                     cache, ctx.n_tokens)
             jax.block_until_ready(out[exe.codec.leaves[0]])
-        except BaseException:
+        except BaseException as err:
             feed.close(raise_errors=False)
+            # a storage fault the feed recorded reaches the caller as
+            # itself (the caller falls back to whole-file reads); any
+            # other failure of the scan propagates unchanged
+            if isinstance(feed.error, (ChunkCorruptError, OSError)):
+                raise feed.error from err
             raise
         feed.close()
+        with self._flags_lock:
+            self.pipelined_restores += 1
         return out
 
     def _feed_positions(self, ctx: Context, idxs: List[int]) -> np.ndarray:

@@ -378,6 +378,11 @@ class LayerFeed:
                 if not e.is_set():
                     e.set()
 
+    @property
+    def error(self) -> Optional[BaseException]:
+        """What the I/O thread raised, if anything (after ``close``)."""
+        return self._error
+
     def fetch(self, layer: int) -> Dict[str, np.ndarray]:
         l = int(layer)
         self._events[l].wait()

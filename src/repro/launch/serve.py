@@ -23,9 +23,11 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import tempfile
 import threading
 import time
+from pathlib import Path
 
 import jax
 
@@ -34,6 +36,21 @@ from repro.core.scheduler import ServiceRouter
 from repro.core.service import LLMSConfig, LLMService, POLICIES
 from repro.models.registry import build_model
 from repro.trace.synth import PATTERNS, synthesize
+
+# src/repro/launch/serve.py -> the root of the checkout
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says, else in ``.jax_cache/`` at the
+    root of the checkout.  The path must not move between runs (it is
+    the only place a later run looks), so it is never a temp name.
+    -> the directory in use."""
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(CHECKOUT / ".jax_cache"))
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_priority_mix(mix: str, n_apps: int):
@@ -57,7 +74,9 @@ def run_trace(router: ServiceRouter, events, n_apps: int = 1,
     the trace's Poisson arrival gaps in real time (wall seconds per
     trace second, 0 = submit everything immediately) — with a threaded
     router and ``slice_steps`` set, paced foreground arrivals land
-    mid-generation and preempt in-flight background streams."""
+    mid-generation and preempt in-flight background streams.
+    -> (stats, calls): ``calls`` pairs each event with its stream, in
+    submission order (per context, the trace's order)."""
     apps = [router.register_app(f"app{i}", prio) for i, prio in
             enumerate(parse_priority_mix(priority_mix, n_apps))]
     session_of = {}                 # ctx_id -> AppSession
@@ -68,7 +87,7 @@ def run_trace(router: ServiceRouter, events, n_apps: int = 1,
             session_of[ev.ctx_id] = sess
             stubs[ev.ctx_id] = sess.new_ctx()
 
-    streams = []
+    calls = []
     t0 = time.perf_counter()
 
     def submit_all(sess):
@@ -78,9 +97,9 @@ def run_trace(router: ServiceRouter, events, n_apps: int = 1,
                     lag = ev.time * pace - (time.perf_counter() - t0)
                     if lag > 0:
                         time.sleep(lag)
-                streams.append(sess.stream(stubs[ev.ctx_id],
-                                           ev.prompt.tolist(),
-                                           max_new_tokens=max_new))
+                calls.append((ev, sess.stream(stubs[ev.ctx_id],
+                                              ev.prompt.tolist(),
+                                              max_new_tokens=max_new)))
 
     if router.started and n_apps > 1:
         threads = [threading.Thread(target=submit_all, args=(s,))
@@ -93,7 +112,7 @@ def run_trace(router: ServiceRouter, events, n_apps: int = 1,
         for sess in apps:
             submit_all(sess)
     router.drain()
-    errors = [s.error for s in streams if s.error is not None]
+    errors = [s.error for _, s in calls if s.error is not None]
     for e in errors[:3]:
         print(f"  !! dropped call: {type(e).__name__}: {e}")
 
@@ -110,7 +129,7 @@ def run_trace(router: ServiceRouter, events, n_apps: int = 1,
     stats = router.svc.stats()
     stats["router"] = router.stats()
     stats["failed_calls"] = len(errors)
-    return stats
+    return stats, calls
 
 
 def main():
@@ -152,6 +171,7 @@ def main():
                          "arrival gaps (0 = compressed time)")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -171,11 +191,11 @@ def main():
         with ServiceRouter(svc, predict=True, start=args.concurrency > 1,
                            slice_steps=args.slice_steps) as router:
             t0 = time.time()
-            stats = run_trace(router, events,
-                              n_apps=max(1, args.concurrency),
-                              priority_mix=args.priority_mix,
-                              max_new=args.max_new, verbose=True,
-                              pace=args.pace)
+            stats, _ = run_trace(router, events,
+                                 n_apps=max(1, args.concurrency),
+                                 priority_mix=args.priority_mix,
+                                 max_new=args.max_new, verbose=True,
+                                 pace=args.pace)
             stats["wall_s"] = time.time() - t0
             print(json.dumps(stats, indent=1))
 

@@ -103,4 +103,5 @@ def test_active_feed_cleared_after_pipelined_restore():
                 svc.callLLM(stub, rng.randint(1, cfg.vocab, 24).tolist(),
                             max_new_tokens=2)
         assert executor_mod._ACTIVE_FEED is None
+        assert svc.stats()["pipelined_restores"] == pipelined["n"]
     assert pipelined["n"] > 0, "trace never exercised the pipelined path"

@@ -413,6 +413,26 @@ def test_transient_eio_recovered_by_retries():
     assert st["recover_failed"] == 0
 
 
+def test_pipelined_restore_failure_propagates():
+    """Only a storage fault the restore feed recorded falls back to
+    whole-file reads: any other failure of the pipelined scan (a compile
+    or runtime error on the device) fails the call instead of being
+    counted as a storage fault."""
+    svc, cfg = _svc(policy="llms")
+
+    def broken(*a, **kw):
+        raise RuntimeError("scan failed")
+    svc.exe.run_pipelined = broken
+    try:
+        with pytest.raises(RuntimeError, match="scan failed"):
+            _drive(svc, cfg)
+        st = svc.stats()
+    finally:
+        svc.close()
+    assert st["chunks_corrupt_detected"] == st["io_errors_detected"] == 0
+    assert st["pipelined_restores"] == 0
+
+
 def test_enospc_degraded_cycle_token_identity():
     """Disk-full window: degraded mode is entered (AoT off, evictions
     drop dirty payloads), foreground calls keep completing via
