@@ -18,7 +18,7 @@ matching the lock hierarchy DESIGN.md documents:
 
     scheduler.svc  >  scheduler.cv / requests.stream  >
     residency.flags  >  swap.pending  >  store.bytes  >
-    faults.registry / restore.io
+    faults.registry / restore.io / telemetry
 
 Re-entrant acquisition (RLock) and same-name sibling instances never
 add self-edges.  CI runs the tier-1 shards and the ``smoke_ci``

@@ -38,13 +38,14 @@ import hashlib
 import math
 import weakref
 from collections import OrderedDict
-from typing import Any, List, Sequence, Tuple
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.chunks import ChunkCodec, WholeStateCodec
+from repro.core.telemetry import Telemetry
 from repro.models.kvspec import LAYOUT_MIXED, LAYOUT_WINDOW
 
 Array = jax.Array
@@ -117,12 +118,20 @@ def _pow2_buckets(lo: int, hi: int) -> List[int]:
 
 
 class ModelExecutor:
-    """Jitted model entry points + bucket/padding helpers (one model)."""
+    """Jitted model entry points + bucket/padding helpers (one model).
 
-    def __init__(self, model, params, cfg):
+    The paged entries (``paged_extend``/``paged_decode``/``admit16``/
+    ``admit8``/``zero16``/``read16``) dispatch their programs inside an
+    ``exe.<entry>`` span of ``telemetry``, so a profiler trace shows
+    which entry issued each device program; the wait for the results
+    and their copy to the host fall outside it."""
+
+    def __init__(self, model, params, cfg,
+                 telemetry: Optional[Telemetry] = None):
         self.model = model
         self.params = params
         self.cfg = cfg
+        self.tel = telemetry if telemetry is not None else Telemetry()
         mc = model.cfg
         spec = model.kv_spec()
         self.spec = spec
@@ -381,14 +390,16 @@ class ModelExecutor:
         pos = np.arange(n0, n0 + M, dtype=np.int32)
         pos_b = self.bucket_pad(pos, self.pad_slot)
         toks_b = self.bucket_pad(np.asarray(prompt, np.int32), 0)
-        arenas, hidden, dens = self.paged_extend_fn(
-            self.params, jnp.asarray(toks_b)[None], jnp.asarray(pos_b),
-            arenas, jnp.asarray(pt16),
-            None if pt8 is None else jnp.asarray(pt8),
-            None if qmask is None else jnp.asarray(qmask),
-            jnp.int32(n0 + M))
-        logits = np.asarray(self.logits_fn(self.params, hidden[:, M - 1]))[0]
-        return arenas, logits, np.asarray(dens[0], np.float64)
+        with self.tel.span("exe.paged_extend"):
+            arenas, hidden, dens = self.paged_extend_fn(
+                self.params, jnp.asarray(toks_b)[None], jnp.asarray(pos_b),
+                arenas, jnp.asarray(pt16),
+                None if pt8 is None else jnp.asarray(pt8),
+                None if qmask is None else jnp.asarray(qmask),
+                jnp.int32(n0 + M))
+            logits = self.logits_fn(self.params, hidden[:, M - 1])
+            dens = dens[0]
+        return arenas, np.asarray(logits)[0], np.asarray(dens, np.float64)
 
     def paged_decode(self, arenas, toks: Sequence[int], pos: Sequence[int],
                      pt16, pt8, qmask):
@@ -412,14 +423,43 @@ class ModelExecutor:
             pt8_b[:n] = pt8
             qmask_b = np.zeros((nb, C), bool)
             qmask_b[:n] = qmask
-        arenas, logits, mass = self.paged_decode_fn(
-            self.params, jnp.asarray(toks_b), arenas,
-            jnp.asarray(pt16_b),
-            None if pt8_b is None else jnp.asarray(pt8_b),
-            None if qmask_b is None else jnp.asarray(qmask_b),
-            jnp.asarray(pos_b))
+        with self.tel.span("exe.paged_decode"):
+            arenas, logits, mass = self.paged_decode_fn(
+                self.params, jnp.asarray(toks_b), arenas,
+                jnp.asarray(pt16_b),
+                None if pt8_b is None else jnp.asarray(pt8_b),
+                None if qmask_b is None else jnp.asarray(qmask_b),
+                jnp.asarray(pos_b))
         return (arenas, np.asarray(logits)[:n],
                 np.asarray(mass, np.float64)[:n])
+
+    # page writes and reads of the pool (page 0 is the scratch page);
+    # each admission or zeroing rewrites the whole arena
+    def admit16(self, arenas, page: int, blocks):
+        """Write (cs, F) bf16 ``blocks`` into bf16 page ``page``."""
+        with self.tel.span("exe.admit16"):
+            out = self.admit16_fn(arenas, page, blocks)
+        self.tel.count("pages_admitted16")
+        return out
+
+    def admit8(self, arenas, page: int, codes, scales):
+        """Write decode-grid int8 ``codes`` + ``scales`` into quant page
+        ``page``."""
+        with self.tel.span("exe.admit8"):
+            out = self.admit8_fn(arenas, page, codes, scales)
+        self.tel.count("pages_admitted8")
+        return out
+
+    def zero16(self, arenas, page: int):
+        with self.tel.span("exe.zero16"):
+            out = self.zero16_fn(arenas, page)
+        self.tel.count("pages_zeroed")
+        return out
+
+    def read16(self, arenas, page: int):
+        """bf16 page ``page`` as (cs, F) blocks per leaf."""
+        with self.tel.span("exe.read16"):
+            return self.read16_fn(arenas, page)
 
     def run_pipelined(self, feed, toks_b, miss_b, io_pos_b, cache, n_total):
         """Dispatch the layer-pipelined recompute scan, with ``feed``

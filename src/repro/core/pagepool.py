@@ -43,9 +43,10 @@ QUANT = 2
 class PagePool:
     """Page tables + free lists over the executor's page arenas."""
 
-    def __init__(self, exe, ctxs):
+    def __init__(self, exe, ctxs, telemetry):
         self.exe = exe
         self.ctxs = ctxs
+        self.tel = telemetry
         self.cs = exe.cs
         self.pages_per_ctx = exe.pages_per_ctx
         self.arenas = exe.init_arenas()
@@ -58,7 +59,6 @@ class PagePool:
         # (kind, page) -> (cid, chunk-index), for debugging/invariants
         self._owner: Dict[Tuple[int, int], Tuple[int, int]] = {}
         self._lru: "OrderedDict[int, None]" = OrderedDict()
-        self.page_faults = 0        # admissions (DRAM/disk -> pool pages)
         self.pt_switch_ins = 0      # chunk switch-ins = pure table reads
         self.admit_switch_ins = 0   # chunk switch-ins that paid an admit
         self.reclaims = 0           # whole-context reclaim evictions
@@ -208,7 +208,10 @@ class PagePool:
             "pool_pages8_total": self.exe.pool_pages8 - 1,
             "pool_pages8_used": (self.exe.pool_pages8 - 1
                                  - len(self._free8)),
-            "pool_page_faults": self.page_faults,
+            # admissions (DRAM/disk -> pool pages), counted by the
+            # executor's admit entries in the service's registry
+            "pool_page_faults": (self.tel.counter("pages_admitted16")
+                                 + self.tel.counter("pages_admitted8")),
             "pool_pt_switch_ins": self.pt_switch_ins,
             "pool_admit_switch_ins": self.admit_switch_ins,
             "pool_reclaims": self.reclaims,

@@ -50,6 +50,7 @@ from repro.core.pipeline import PipelineProfile, fit_linear, plan_split
 from repro.core.restore import (LayerFeed, read_chunk_file,
                                 verify_chunk_file, write_chunk_file)
 from repro.core.swap import AsyncSwapper, DiskStore
+from repro.core.telemetry import Telemetry
 
 
 class SlotAllocator:
@@ -112,8 +113,10 @@ class ResidencyEngine:
 
     def __init__(self, exe: ModelExecutor, ctxs: ContextStore,
                  store: DiskStore, swapper: AsyncSwapper,
-                 queue: LCTRUQueue, mem: MemoryManager, cfg):
+                 queue: LCTRUQueue, mem: MemoryManager, cfg,
+                 telemetry: Telemetry):
         self.exe = exe
+        self.tel = telemetry
         self.ctxs = ctxs
         self.store = store
         self.swapper = swapper
@@ -128,7 +131,7 @@ class ResidencyEngine:
         # the slot-like A/B baseline (pages freed at swap-out, every
         # switch-in re-admits).
         self.pool: Optional[PagePool] = (
-            PagePool(exe, ctxs) if exe.paged else None)
+            PagePool(exe, ctxs, telemetry) if exe.paged else None)
         self.pool_persist = True
         self.profile = PipelineProfile()
         self.profiled = False
@@ -170,7 +173,8 @@ class ResidencyEngine:
         self.degraded_entries = 0
         self.degraded_exits = 0
         self._degrade_ticks = 0
-        self.chunks_recovered_recompute = 0
+        # chunks rebuilt by recompute count as the registry's
+        # ``chunks_recovered`` (fault_stats' chunks_recovered_recompute)
         self.chunks_corrupt_detected = 0
         self.io_errors_detected = 0
         self.evict_dropped = 0
@@ -256,7 +260,8 @@ class ResidencyEngine:
             "degraded_mode": int(self.degraded),
             "degraded_entries": self.degraded_entries,
             "degraded_exits": self.degraded_exits,
-            "chunks_recovered_recompute": self.chunks_recovered_recompute,
+            "chunks_recovered_recompute": self.tel.counter(
+                "chunks_recovered"),
             "chunks_corrupt_detected": self.chunks_corrupt_detected,
             "io_errors_detected": self.io_errors_detected,
             "evict_dropped": self.evict_dropped,
@@ -397,15 +402,16 @@ class ResidencyEngine:
 
         # ---- untimed: resident chunks (table read / first admission) -- #
         admitted = 0
-        for i, m in sorted(ctx.chunks.items()):
-            if m.in_memory:
-                if pool.kind(ctx.cid, i) == 0:
-                    self._admit_chunk(ctx, i, quant_mode)
-                    admitted += 1
-                else:
-                    pool.pt_switch_ins += 1
-                self.queue.touch((ctx.cid, i), m.bits)
-                m.last_access = time.time()
+        with self.tel.span("switch_in.admit"):
+            for i, m in sorted(ctx.chunks.items()):
+                if m.in_memory:
+                    if pool.kind(ctx.cid, i) == 0:
+                        self._admit_chunk(ctx, i, quant_mode)
+                        admitted += 1
+                    else:
+                        pool.pt_switch_ins += 1
+                    self.queue.touch((ctx.cid, i), m.bits)
+                    m.last_access = time.time()
         pool.admit_switch_ins += admitted
 
         # ---- timed: reclaim + disk restore of missing chunks ---------- #
@@ -413,7 +419,8 @@ class ResidencyEngine:
         missing = sorted(i for i, m in ctx.chunks.items() if not m.in_memory)
         if missing:
             need = sum(ctx.chunks[i].nbytes for i in missing)
-            self.mem.reclaim(need, self.evict, locked={ctx.cid})
+            with self.tel.span("switch_in.reclaim"):
+                self.mem.reclaim(need, self.evict, locked={ctx.cid})
             # I/O-first restore: eviction normally persists a chunk
             # before it leaves memory, so the payload bytes exist on
             # disk — except after a storage fault (failed write, corrupt
@@ -427,22 +434,27 @@ class ResidencyEngine:
                 cc = None
                 if i in futs:
                     try:
-                        cc = self._fut_result(futs[i])
+                        with self.tel.span("switch_in.read"):
+                            cc = self._fut_result(futs[i])
                     except SwapTimeoutError:
                         raise
                     except (ChunkCorruptError, OSError) as err:
                         self._note_read_failure(err)
                 if cc is not None:
-                    self._mark_loaded(ctx, i, payload=cc)
-                    # a surviving page (evicted-while-busy chunk) already
-                    # holds exactly this payload's values — skip the admit
-                    if pool.kind(ctx.cid, i) == 0:
-                        self._admit_chunk(ctx, i, quant_mode)
+                    with self.tel.span("switch_in.admit"):
+                        self._mark_loaded(ctx, i, payload=cc)
+                        # a surviving page (evicted-while-busy chunk)
+                        # already holds exactly this payload's values —
+                        # skip the admit
+                        if pool.kind(ctx.cid, i) == 0:
+                            self._admit_chunk(ctx, i, quant_mode)
                 else:
-                    self._recover_chunk_paged(ctx, i, quant_mode)
+                    with self.tel.span("switch_in.recover"):
+                        self._recover_chunk_paged(ctx, i, quant_mode)
         if admitted or missing:
-            jax.block_until_ready(
-                pool.arenas[exe.codec.leaves[0] + "16"])
+            with self.tel.span("switch_in.sync"):
+                jax.block_until_ready(
+                    pool.arenas[exe.codec.leaves[0] + "16"])
         return None, time.perf_counter() - t0
 
     def _admit_chunk(self, ctx: Context, i: int, quant_mode: bool):
@@ -466,15 +478,14 @@ class ResidencyEngine:
                         self._payload_blocks(cc), head_dims)
                     ctx.qmemo[i] = qc
             page = pool.alloc8(ctx.cid, i)
-            pool.arenas = exe.admit8_fn(
+            pool.arenas = exe.admit8(
                 pool.arenas, page,
                 {n: jnp.asarray(qc.data[n][0]) for n in codec.leaves},
                 {n: jnp.asarray(qc.data[n][1]) for n in codec.leaves})
         else:
             blocks = self._payload_blocks(cc)
             page = pool.alloc16(ctx.cid, i)
-            pool.arenas = exe.admit16_fn(pool.arenas, page, blocks)
-        pool.page_faults += 1
+            pool.arenas = exe.admit16(pool.arenas, page, blocks)
 
     def ensure_extend_range(self, ctx: Context, c_lo: int, c_hi: int):
         """Give chunks [c_lo, c_hi] writable bf16 pages ahead of a paged
@@ -491,8 +502,7 @@ class ResidencyEngine:
                 blocks = self._payload_blocks(ctx.payload[ci])
                 pool.free_chunk(ctx.cid, ci)
                 page = pool.alloc16(ctx.cid, ci)
-                pool.arenas = self.exe.admit16_fn(pool.arenas, page, blocks)
-                pool.page_faults += 1
+                pool.arenas = self.exe.admit16(pool.arenas, page, blocks)
             else:
                 self._alloc_fresh16(ctx.cid, ci)
 
@@ -507,7 +517,7 @@ class ResidencyEngine:
         positions are exactly zero — and some of them are attended (and
         encoded at swap-out), so both paths must agree there."""
         page = self.pool.alloc16(cid, ci)
-        self.pool.arenas = self.exe.zero16_fn(self.pool.arenas, page)
+        self.pool.arenas = self.exe.zero16(self.pool.arenas, page)
 
     # -- recompute-based recovery (ladder step 2, DESIGN.md §6) -------- #
     @staticmethod
@@ -546,7 +556,7 @@ class ResidencyEngine:
             pool.arenas, _, _ = exe.paged_extend(pool.arenas, toks, a,
                                                  pt16, pt8, qmask)
         page = int(pool._tables[ctx.cid]["p16"][i])
-        return exe.read16_fn(pool.arenas, page)
+        return exe.read16(pool.arenas, page)
 
     @requires_serialized
     def _recover_chunk_paged(self, ctx: Context, i: int, quant_mode: bool):
@@ -567,7 +577,7 @@ class ResidencyEngine:
             # the authoritative values — rebuild the payload from it
             # instead of recomputing
             page = int(self.pool._tables[ctx.cid]["p16"][i])
-            blocks = self.exe.read16_fn(self.pool.arenas, page)
+            blocks = self.exe.read16(self.pool.arenas, page)
         else:
             blocks = self._recompute_blocks_paged(ctx, i)
         want_quant = self.exe.quant_resident and m.bits == 8
@@ -589,8 +599,7 @@ class ResidencyEngine:
             self._dirty_cids.add(ctx.cid)
         self.mem.register((ctx.cid, i), m.nbytes, m.bits)
         self._admit_chunk(ctx, i, quant_mode)
-        with self._flags_lock:
-            self.chunks_recovered_recompute += 1
+        self.tel.count("chunks_recovered")
 
     def _plan_restore(self, ctx, missing: List[int]
                       ) -> Tuple[List[int], List[int]]:
@@ -725,8 +734,7 @@ class ResidencyEngine:
                 else:
                     m.dirty, m.on_disk = True, False
                     self._dirty_cids.add(ctx.cid)
-                with self._flags_lock:
-                    self.chunks_recovered_recompute += 1
+                self.tel.count("chunks_recovered")
             else:
                 m.dirty = False               # already on disk
             self.mem.register((ctx.cid, i), m.nbytes, m.bits)
@@ -784,8 +792,12 @@ class ResidencyEngine:
         in-flight same-key AoT write: ``flush_dirty`` marks ``on_disk``
         when it SUBMITS the write, so reading the path directly races
         the writer's ``os.replace`` (FileNotFoundError under load)."""
-        return self.swapper.submit(key, read_chunk_file,
-                                   self.store._path(key))
+        path = self.store._path(key)
+
+        def job():
+            with self.tel.span("store.read"):
+                return read_chunk_file(path)
+        return self.swapper.submit(key, job)
 
     def _read_chunk(self, key):
         """Synchronous chunk-file read; blocks the caller on any
@@ -797,10 +809,11 @@ class ResidencyEngine:
         def _on_retry(_k, _e):
             self.swapper.note_retry()
 
-        return with_retries(lambda: read_chunk_file(self.store._path(key)),
-                            attempts=self.swapper.retries,
-                            base_s=self.swapper.retry_base_s,
-                            on_retry=_on_retry)
+        with self.tel.span("store.read"):
+            return with_retries(
+                lambda: read_chunk_file(self.store._path(key)),
+                attempts=self.swapper.retries,
+                base_s=self.swapper.retry_base_s, on_retry=_on_retry)
 
     @requires_serialized
     def _mark_loaded(self, ctx, i: int, payload):
@@ -812,6 +825,7 @@ class ResidencyEngine:
         m.in_memory, m.dirty = True, False
         m.quant = isinstance(payload, QuantResidentChunk)
         self.mem.register((ctx.cid, i), m.nbytes, m.bits)
+        self.tel.count("chunks_read")
 
     # -- whole-context policies (swap / lmk) ----------------------------- #
     @requires_serialized
@@ -839,8 +853,7 @@ class ResidencyEngine:
                 # the whole context rebuilds from its resident text
                 self._note_read_failure(err)
                 self.store.drop_bytes((ctx.cid, -1))
-                with self._flags_lock:
-                    self.chunks_recovered_recompute += 1
+                self.tel.count("chunks_recovered")
         if ctx.whole is not None:
             pass                                       # resident
         else:
@@ -887,28 +900,34 @@ class ResidencyEngine:
 
     # -- payload codecs ------------------------------------------------- #
     def _payload_blocks(self, cc) -> Dict[str, jax.Array]:
-        if isinstance(cc, QuantResidentChunk):
-            return self.exe.codec.dequantize_resident(cc)
-        if cc.bits == 16:
-            return {k: jnp.asarray(p).astype(jnp.bfloat16)
-                    for k, (p, _) in cc.data.items()}
-        return self.exe.codec.decompress(cc)
+        """Payload -> (T, F) bf16 blocks on the device (the codec's
+        dequantize programs, in a ``codec.dequantize`` span)."""
+        with self.tel.span("codec.dequantize"):
+            if isinstance(cc, QuantResidentChunk):
+                return self.exe.codec.dequantize_resident(cc)
+            if cc.bits == 16:
+                return {k: jnp.asarray(p).astype(jnp.bfloat16)
+                        for k, (p, _) in cc.data.items()}
+            return self.exe.codec.decompress(cc)
 
     def _encode_blocks(self, blocks, bits: int, quant: bool):
         """(T, F) blocks -> payload: decode-grid QuantResidentChunk when
-        ``quant``, else the storage codec at ``bits``."""
+        ``quant``, else the storage codec at ``bits`` (host arrays, in a
+        ``codec.quantize`` span)."""
         codec = self.exe.codec
-        if quant:
-            head_dims = {n: self.exe.work_cache[n].shape[-1]
-                         for n in codec.leaves}
-            return codec.quantize_resident_blocks(blocks, head_dims)
-        if bits == 16:
-            return CompressedChunk(
-                bits=16, n_tokens=next(iter(blocks.values())).shape[0],
-                data={k: (np.asarray(v, np.float16), np.zeros(0, np.float32))
-                      for k, v in blocks.items()},
-                shapes={k: tuple(v.shape) for k, v in blocks.items()})
-        return codec.compress_blocks(blocks, bits)
+        with self.tel.span("codec.quantize"):
+            if quant:
+                head_dims = {n: self.exe.work_cache[n].shape[-1]
+                             for n in codec.leaves}
+                return codec.quantize_resident_blocks(blocks, head_dims)
+            if bits == 16:
+                return CompressedChunk(
+                    bits=16, n_tokens=next(iter(blocks.values())).shape[0],
+                    data={k: (np.asarray(v, np.float16),
+                              np.zeros(0, np.float32))
+                          for k, v in blocks.items()},
+                    shapes={k: tuple(v.shape) for k, v in blocks.items()})
+            return codec.compress_blocks(blocks, bits)
 
     def _make_payload(self, cache, i: int, bits: int, quant: bool = False):
         """Encode chunk i from the slot cache.  A mixed cache is read
@@ -931,7 +950,7 @@ class ResidencyEngine:
         exe, pool = self.exe, self.pool
         if pool.kind(ctx.cid, i) == BF16:
             page = int(pool._tables[ctx.cid]["p16"][i])
-            blocks = exe.read16_fn(pool.arenas, page)
+            blocks = exe.read16(pool.arenas, page)
         else:
             cc = ctx.payload.get(i)
             if cc is not None:
@@ -1032,6 +1051,7 @@ class ResidencyEngine:
                                             quant=want_quant)
                 ctx.payload[i] = cc
                 ctx.qmemo.pop(i, None)
+                self.tel.count(f"chunks_encoded.{want}")
                 m.bits, m.nbytes, m.n_covered = want, cc.nbytes, covered
                 m.quant = want_quant
                 m.dirty, m.in_memory, m.on_disk = True, True, False
@@ -1140,7 +1160,8 @@ class ResidencyEngine:
         path = self.store._path(key)
 
         def work():
-            n = write_chunk_file(path, cc, self.exe.n_layers)
+            with self.tel.span("store.write"):
+                n = write_chunk_file(path, cc, self.exe.n_layers)
             self.store.set_bytes(key, n)
         self.swapper.submit(key, work)
         return True

@@ -156,6 +156,9 @@ class ServiceRouter:
                  clock: Optional[Callable[[], float]] = None,
                  record_limit: Optional[int] = None):
         self.svc = svc
+        # the service's span/counter registry: the router's spans go
+        # there too, so one snapshot covers every layer
+        self.telemetry = svc.telemetry
         self.slice_steps = int(slice_steps)
         self.decode_batch = max(1, int(getattr(svc, "decode_batch", 1)))
         # ``clock`` replaces wall time for ALL QoS timestamps (enqueue,
@@ -338,15 +341,18 @@ class ServiceRouter:
     def _loop(self):
         while True:
             with self._cv:
-                while not self._queue and not self._stop:
-                    self._cv.wait()
+                if not self._queue and not self._stop:
+                    with self.telemetry.span("router.wait"):
+                        while not self._queue and not self._stop:
+                            self._cv.wait()
                 if self._stop and not self._queue:
                     return
                 jobs = self._pop_locked(self.decode_batch, set())
                 self._inflight += 1
             try:
                 if jobs:
-                    self._run_batch(jobs)
+                    with self.telemetry.span("router.dispatch"):
+                        self._run_batch(jobs)
             finally:
                 with self._cv:
                     self._inflight -= 1
@@ -415,61 +421,62 @@ class ServiceRouter:
         """Admit one popped job into the running batch: begin (or
         resume) its generation so it holds a decode slot.  Returns True
         iff the job joined ``active`` (False: cancelled or failed)."""
-        stream: GenerationStream = job["stream"]
-        fut: Optional[Future] = job["future"]
-        if job["state"] is None:
-            # t_start doubles as a "future already running" marker: a
-            # watchdog-requeued fresh job must not notify its Future a
-            # second time (set_running_or_notify_cancel raises once the
-            # Future left PENDING)
-            if (job["t_start"] is None and fut is not None
-                    and not fut.set_running_or_notify_cancel()):
-                stream.finish(cancelled=True)
-                return False
-            if stream.cancel_requested:          # cancelled while queued
-                stream.finish(cancelled=True)
-                return False
-            if job["t_start"] is None:
-                job["t_start"] = self._now()
-        try:
-            st = job["state"]
-            if st is None:
-                cid = job["stub"].ctx_id
-                if self._pred_next is not None:
-                    self._pred_total += 1
-                    self._pred_hits += self._pred_next == cid
-                job["state"] = self.svc.begin_call(job["stub"],
-                                                   job["request"])
-                if self.on_begin is not None:
-                    self.on_begin(job, False)
-            elif st.suspended:
-                if stream.cancel_requested:      # cancelled while preempted
-                    self._complete(job, cancelled=True)
+        with self.telemetry.span("router.admit", ctx=job["stub"].ctx_id):
+            stream: GenerationStream = job["stream"]
+            fut: Optional[Future] = job["future"]
+            if job["state"] is None:
+                # t_start doubles as a "future already running" marker: a
+                # watchdog-requeued fresh job must not notify its Future a
+                # second time (set_running_or_notify_cancel raises once the
+                # Future left PENDING)
+                if (job["t_start"] is None and fut is not None
+                        and not fut.set_running_or_notify_cancel()):
+                    stream.finish(cancelled=True)
                     return False
-                self.svc.resume_call(st)
-                if self.on_begin is not None:
-                    self.on_begin(job, True)
-            active.append(job)
-            return True
-        except SwapTimeoutError as e:
-            # per-slice watchdog (DESIGN.md §6): the switch-in's swap
-            # read exceeded swap_deadline_s.  Turn the hang into a
-            # preemption — requeue under the original admission key so
-            # the job retries ahead of later arrivals — bounded so a
-            # permanently wedged store still fails the call
-            job["watchdogs"] = job.get("watchdogs", 0) + 1
-            if job["watchdogs"] > 3:
+                if stream.cancel_requested:          # cancelled while queued
+                    stream.finish(cancelled=True)
+                    return False
+                if job["t_start"] is None:
+                    job["t_start"] = self._now()
+            try:
+                st = job["state"]
+                if st is None:
+                    cid = job["stub"].ctx_id
+                    if self._pred_next is not None:
+                        self._pred_total += 1
+                        self._pred_hits += self._pred_next == cid
+                    job["state"] = self.svc.begin_call(job["stub"],
+                                                       job["request"])
+                    if self.on_begin is not None:
+                        self.on_begin(job, False)
+                elif st.suspended:
+                    if stream.cancel_requested:      # cancelled while preempted
+                        self._complete(job, cancelled=True)
+                        return False
+                    self.svc.resume_call(st)
+                    if self.on_begin is not None:
+                        self.on_begin(job, True)
+                active.append(job)
+                return True
+            except SwapTimeoutError as e:
+                # per-slice watchdog (DESIGN.md §6): the switch-in's swap
+                # read exceeded swap_deadline_s.  Turn the hang into a
+                # preemption — requeue under the original admission key so
+                # the job retries ahead of later arrivals — bounded so a
+                # permanently wedged store still fails the call
+                job["watchdogs"] = job.get("watchdogs", 0) + 1
+                if job["watchdogs"] > 3:
+                    self._fail(job, e)
+                else:
+                    self.watchdog_preempts += 1
+                    self._requeue(job)
+                return False
+            except Exception as e:              # report to the submitting app
                 self._fail(job, e)
-            else:
-                self.watchdog_preempts += 1
-                self._requeue(job)
-            return False
-        except Exception as e:              # report to the submitting app
-            self._fail(job, e)
-            return False
-        except BaseException as e:          # KeyboardInterrupt/SystemExit:
-            self._fail(job, e)              # fail the job AND abort dispatch
-            raise
+                return False
+            except BaseException as e:          # KeyboardInterrupt/SystemExit:
+                self._fail(job, e)              # fail the job AND abort dispatch
+                raise
 
     @requires_lock("_svc_lock")
     def _run_slice(self, active: List[dict], refill: bool = False):
@@ -514,20 +521,22 @@ class ServiceRouter:
                         self.joins_mid_slice += 1
             if not live:
                 return
-            toks = self.svc.decode_step_batch([j["state"] for j in live])
-            self.decode_rounds += 1
-            self.decoded_tokens += sum(t is not None for t in toks)
-            self._sample_queue_depth()
-            if self.on_round is not None:
-                # hook BEFORE the pushes: a virtual clock advanced here
-                # stamps this round's tokens at the post-round instant
-                self.on_round(live)
-            for job, tok in zip(live, toks):
-                if tok is not None:
-                    job["stream"].push(tok)
-                if job["state"].exhausted:
-                    active.remove(job)
-                    self._complete(job)
+            with self.telemetry.span("router.round"):
+                toks = self.svc.decode_step_batch([j["state"] for j in live])
+                self.decode_rounds += 1
+                self.decoded_tokens += sum(t is not None for t in toks)
+                self._sample_queue_depth()
+                if self.on_round is not None:
+                    # hook BEFORE the pushes: a virtual clock advanced
+                    # here stamps this round's tokens at the post-round
+                    # instant
+                    self.on_round(live)
+                for job, tok in zip(live, toks):
+                    if tok is not None:
+                        job["stream"].push(tok)
+                    if job["state"].exhausted:
+                        active.remove(job)
+                        self._complete(job)
             n += 1
 
     @requires_lock("_svc_lock")
@@ -624,43 +633,44 @@ class ServiceRouter:
     @requires_lock("_svc_lock")
     def _complete(self, job, cancelled: bool = False):
         """finish_call + records + prediction hook (under _svc_lock)."""
-        st, stream, fut = job["state"], job["stream"], job["future"]
-        sess: AppSession = job["session"]
-        cid = job["stub"].ctx_id
-        self.svc.finish_call(st)
-        # capture under the lock: another session's call must not slip a
-        # record in between
-        rec = self.svc.records[-1] if self.svc.records else {}
-        self._after_call(cid)
-        t_end = self._now()
-        entry = {
-            "app": sess.name, "priority": job["prio"], "ctx": cid,
-            "wait_s": job["t_start"] - job["t_enqueue"],
-            "service_s": t_end - job["t_start"],
-            "switch_s": rec.get("switch_s", 0.0),
-            "n_preempts": stream.n_preempts,
-            "cancelled": cancelled,
-        }
-        if stream.t_first_token is not None:
-            entry["ttft_s"] = stream.t_first_token - job["t_enqueue"]
-            tbts = stream.tbt()
-            if tbts:
-                entry["tbt_mean_s"] = float(np.mean(tbts))
-        acc = self._acc[job["prio"]]
-        acc["wait"].append(entry["wait_s"])
-        acc["serv"].append(entry["service_s"])
-        if "ttft_s" in entry:
-            acc["ttft"].append(entry["ttft_s"])
-        if "tbt_mean_s" in entry:
-            acc["tbt"].append(entry["tbt_mean_s"])
-        self._acc_preempts[job["prio"]] += stream.n_preempts
-        self._acc_cancelled[job["prio"]] += bool(cancelled)
-        self.call_records.append(entry)
-        stream.finish(cancelled=cancelled)
-        if self.on_complete is not None:
-            self.on_complete(job, cancelled)
-        if fut is not None:
-            fut.set_result((job["stub"], list(stream.tokens)))
+        with self.telemetry.span("router.complete", ctx=job["stub"].ctx_id):
+            st, stream, fut = job["state"], job["stream"], job["future"]
+            sess: AppSession = job["session"]
+            cid = job["stub"].ctx_id
+            self.svc.finish_call(st)
+            # capture under the lock: another session's call must not slip a
+            # record in between
+            rec = self.svc.records[-1] if self.svc.records else {}
+            self._after_call(cid)
+            t_end = self._now()
+            entry = {
+                "app": sess.name, "priority": job["prio"], "ctx": cid,
+                "wait_s": job["t_start"] - job["t_enqueue"],
+                "service_s": t_end - job["t_start"],
+                "switch_s": rec.get("switch_s", 0.0),
+                "n_preempts": stream.n_preempts,
+                "cancelled": cancelled,
+            }
+            if stream.t_first_token is not None:
+                entry["ttft_s"] = stream.t_first_token - job["t_enqueue"]
+                tbts = stream.tbt()
+                if tbts:
+                    entry["tbt_mean_s"] = float(np.mean(tbts))
+            acc = self._acc[job["prio"]]
+            acc["wait"].append(entry["wait_s"])
+            acc["serv"].append(entry["service_s"])
+            if "ttft_s" in entry:
+                acc["ttft"].append(entry["ttft_s"])
+            if "tbt_mean_s" in entry:
+                acc["tbt"].append(entry["tbt_mean_s"])
+            self._acc_preempts[job["prio"]] += stream.n_preempts
+            self._acc_cancelled[job["prio"]] += bool(cancelled)
+            self.call_records.append(entry)
+            stream.finish(cancelled=cancelled)
+            if self.on_complete is not None:
+                self.on_complete(job, cancelled)
+            if fut is not None:
+                fut.set_result((job["stub"], list(stream.tokens)))
 
     def _fail(self, job, err: BaseException):
         st = job["state"]
@@ -799,6 +809,7 @@ class ServiceRouter:
             "joins_mid_slice": self.joins_mid_slice,
             "tokens_per_round": (self.decoded_tokens / self.decode_rounds
                                  if self.decode_rounds else 0.0),
+            "telemetry": self.telemetry.snapshot(),
         }
         if self._qd_n:
             qs = self._qd_samples or [0]
@@ -844,8 +855,10 @@ class ServiceRouter:
     @requires_serialized
     def reset_stats(self):
         """Clear per-call records AND the streaming accumulators (warm
-        pass -> measured pass); cumulative counters restart too."""
+        pass -> measured pass); cumulative counters and the telemetry
+        spans and counters restart too."""
         self.call_records.clear()
+        self.telemetry.reset()
         self._acc.clear()
         self._acc_preempts.clear()
         self._acc_cancelled.clear()

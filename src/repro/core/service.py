@@ -44,6 +44,7 @@ from repro.core.lifecycle import LCTRUQueue, MemoryManager
 from repro.core.requests import GenerationRequest, SamplingParams
 from repro.core.residency import ResidencyEngine
 from repro.core.swap import AsyncSwapper, DiskStore
+from repro.core.telemetry import Telemetry
 from repro.models.api import ModelBase
 
 POLICIES = ("llms", "llms_nocomp", "llms_nopipe", "llms_nolife",
@@ -143,7 +144,6 @@ class GenerationState:
     t_switch: float = 0.0
     t_assemble: float = 0.0
     t_infer: float = 0.0
-    t_swapout: float = 0.0
     n_preempts: int = 0
     suspended: bool = False
     done: bool = False
@@ -163,16 +163,20 @@ class LLMService:
                  queue: Optional[LCTRUQueue] = None,
                  mem: Optional[MemoryManager] = None,
                  cid_alloc: Any = None,
-                 records: Any = None):
+                 records: Any = None,
+                 telemetry: Optional[Telemetry] = None):
         # the keyword-only substrate arguments let a ZooService run
         # several family executors against ONE disk store / swapper /
-        # LCTRU queue / byte budget / cid space / records stream
-        # (DESIGN.md §4); standalone construction builds private ones.
+        # LCTRU queue / byte budget / cid space / records stream /
+        # span registry (DESIGN.md §4); standalone construction builds
+        # private ones.
         self.model, self.params, self.cfg = model, params, cfg
-        self.exe = ModelExecutor(model, params, cfg)
+        self.telemetry = tel = (telemetry if telemetry is not None
+                                else Telemetry())
+        self.exe = ModelExecutor(model, params, cfg, tel)
         if store is None:
             root = cfg.swap_dir or tempfile.mkdtemp(prefix="llms_swap_")
-            store = DiskStore(root)
+            store = DiskStore(root, tel)
         self.store = store
         self._owns_swapper = swapper is None
         self.swapper = swapper if swapper is not None else AsyncSwapper(
@@ -185,7 +189,8 @@ class LLMService:
         self.ctxs = ContextStore(self.mem, self.store, self.exe.s_work,
                                  cid_alloc=cid_alloc)
         self.res = ResidencyEngine(self.exe, self.ctxs, self.store,
-                                   self.swapper, self.queue, self.mem, cfg)
+                                   self.swapper, self.queue, self.mem, cfg,
+                                   tel)
         self.records: Any = (records if records is not None
                              else (deque(maxlen=cfg.record_limit)
                                    if cfg.record_limit else []))
@@ -259,53 +264,58 @@ class LLMService:
         the context in (the measured QoS path), prefill the prompt, and
         sample the first token.  Nothing is emitted yet — the first
         ``decode_step`` emits it."""
-        ctx = self.ctxs.get(stub.ctx_id)
-        if ctx.busy:
-            # a suspended (slice-preempted) generation owns this context's
-            # token tail; starting another call would let condense/append
-            # rewrite state out from under it.  The router avoids this
-            # ordering (same-context arrivals don't preempt); reaching it
-            # means the app raced two requests on one context.
-            raise RuntimeError(
-                f"ctx {ctx.cid} has a suspended in-flight generation; "
-                "await or cancel its stream before a new call")
-        prompt = np.asarray(request.prompt, np.int32)
-        total_new = len(prompt) + request.max_new_tokens
-        assert total_new <= self.exe.max_request_tokens, "exceeds half window"
-        if ctx.n_tokens + total_new > self.exe.n_slots:
-            self._condense(ctx, keep=self.exe.n_slots // 2)
+        with self.telemetry.span("service.begin_call", ctx=stub.ctx_id):
+            ctx = self.ctxs.get(stub.ctx_id)
+            if ctx.busy:
+                # a suspended (slice-preempted) generation owns this
+                # context's token tail; starting another call would let
+                # condense/append rewrite state out from under it.  The
+                # router avoids this ordering (same-context arrivals
+                # don't preempt); reaching it means the app raced two
+                # requests on one context.
+                raise RuntimeError(
+                    f"ctx {ctx.cid} has a suspended in-flight generation; "
+                    "await or cancel its stream before a new call")
+            prompt = np.asarray(request.prompt, np.int32)
+            total_new = len(prompt) + request.max_new_tokens
+            assert total_new <= self.exe.max_request_tokens, \
+                "exceeds half window"
+            if ctx.n_tokens + total_new > self.exe.n_slots:
+                self._condense(ctx, keep=self.exe.n_slots // 2)
 
-        st = GenerationState(ctx=ctx, request=request,
-                             sampler=request.sampling.make_sampler(),
-                             prompt_len=len(prompt))
-        self._switch_in(st)
-        try:
-            # inference: extend with the new prompt (prefill)
-            t1 = time.perf_counter()
-            n0 = ctx.n_tokens
-            ctx.tokens[n0:n0 + len(prompt)] = prompt
-            if self.paged:
-                pool = self.res.pool
-                cs = self.exe.cs
-                self.res.ensure_extend_range(
-                    ctx, n0 // cs, (n0 + len(prompt) - 1) // cs)
-                pt16, pt8, qmask = pool.rows([ctx.cid])
-                pool.arenas, logits, dens = self.exe.paged_extend(
-                    pool.arenas, prompt, n0, pt16, pt8, qmask)
-            else:
-                cache, logits, dens = self.exe.extend(st.cache, prompt, n0)
-                st.cache = cache
-            self.ctxs.acc_density(ctx, dens, n0 + len(prompt))
-            ctx.n_tokens += len(prompt)
-            if request.max_new_tokens > 0:
-                st.next_tok = st.sampler(logits)
-            st.t_infer += time.perf_counter() - t1
-            ctx.busy += 1
-        except BaseException:       # failed prefill must not leak the slot
-            self.res.slots.release(ctx.cid)
-            st.slot = st.cache = None
-            raise
-        return st
+            st = GenerationState(ctx=ctx, request=request,
+                                 sampler=request.sampling.make_sampler(),
+                                 prompt_len=len(prompt))
+            self._switch_in(st)
+            try:
+                # inference: extend with the new prompt (prefill)
+                with self.telemetry.span("service.prefill", ctx=ctx.cid):
+                    t1 = time.perf_counter()
+                    n0 = ctx.n_tokens
+                    ctx.tokens[n0:n0 + len(prompt)] = prompt
+                    if self.paged:
+                        pool = self.res.pool
+                        cs = self.exe.cs
+                        self.res.ensure_extend_range(
+                            ctx, n0 // cs, (n0 + len(prompt) - 1) // cs)
+                        pt16, pt8, qmask = pool.rows([ctx.cid])
+                        pool.arenas, logits, dens = self.exe.paged_extend(
+                            pool.arenas, prompt, n0, pt16, pt8, qmask)
+                    else:
+                        cache, logits, dens = self.exe.extend(
+                            st.cache, prompt, n0)
+                        st.cache = cache
+                    self.ctxs.acc_density(ctx, dens, n0 + len(prompt))
+                    ctx.n_tokens += len(prompt)
+                    if request.max_new_tokens > 0:
+                        st.next_tok = st.sampler(logits)
+                    st.t_infer += time.perf_counter() - t1
+                ctx.busy += 1
+            except BaseException:   # failed prefill must not leak the slot
+                self.res.slots.release(ctx.cid)
+                st.slot = st.cache = None
+                raise
+            return st
 
     @requires_serialized
     def decode_step(self, st: GenerationState) -> Optional[int]:
@@ -323,50 +333,51 @@ class LLMService:
         lone survivor routes through the serial ``decode`` — so with
         decode_batch=1 this IS the serial path, token for token).
         -> emitted tokens parallel to ``sts`` (None where exhausted)."""
-        t1 = time.perf_counter()
-        out: List[Optional[int]] = []
-        live: List[GenerationState] = []
-        fed: List[int] = []
-        for st in sts:
-            if st.done or st.next_tok is None:
-                out.append(None)
-                continue
-            assert not st.suspended, "resume_call before decode_step"
-            ctx = st.ctx
-            tok = st.next_tok
-            st.generated.append(tok)
-            ctx.tokens[ctx.n_tokens] = tok
-            ctx.n_tokens += 1
-            out.append(tok)
-            if len(st.generated) >= st.request.max_new_tokens:
-                # the final emitted token is appended to the text but
-                # never fed (no decode round left): its KV row stays
-                # zero.  Track the hole so recompute-based fault
-                # recovery skips the token too (DESIGN.md §6).
-                ctx.kv_holes.add(ctx.n_tokens - 1)
-                st.next_tok = None
-            else:
-                live.append(st)
-                fed.append(tok)
-        if live:
-            if self.paged:
-                self._decode_round_paged(live, fed)
-            else:
-                # slot mode decodes members serially: the pool carries
-                # the batched path, and non-paged families don't support
-                # per-row positions in one jitted step
-                for st, tok in zip(live, fed):
-                    cache, logits, mass = self.exe.decode(st.cache, tok)
-                    st.cache = cache
-                    self.ctxs.acc_density(st.ctx, mass, st.ctx.n_tokens)
-                    st.next_tok = st.sampler(logits)
-        n_stepped = sum(tok is not None for tok in out)
-        if n_stepped:
-            share = (time.perf_counter() - t1) / n_stepped
-            for st, tok in zip(sts, out):
-                if tok is not None:
-                    st.t_infer += share
-        return out
+        with self.telemetry.span("service.decode_round"):
+            t1 = time.perf_counter()
+            out: List[Optional[int]] = []
+            live: List[GenerationState] = []
+            fed: List[int] = []
+            for st in sts:
+                if st.done or st.next_tok is None:
+                    out.append(None)
+                    continue
+                assert not st.suspended, "resume_call before decode_step"
+                ctx = st.ctx
+                tok = st.next_tok
+                st.generated.append(tok)
+                ctx.tokens[ctx.n_tokens] = tok
+                ctx.n_tokens += 1
+                out.append(tok)
+                if len(st.generated) >= st.request.max_new_tokens:
+                    # the final emitted token is appended to the text but
+                    # never fed (no decode round left): its KV row stays
+                    # zero.  Track the hole so recompute-based fault
+                    # recovery skips the token too (DESIGN.md §6).
+                    ctx.kv_holes.add(ctx.n_tokens - 1)
+                    st.next_tok = None
+                else:
+                    live.append(st)
+                    fed.append(tok)
+            if live:
+                if self.paged:
+                    self._decode_round_paged(live, fed)
+                else:
+                    # slot mode decodes members serially: the pool carries
+                    # the batched path, and non-paged families don't support
+                    # per-row positions in one jitted step
+                    for st, tok in zip(live, fed):
+                        cache, logits, mass = self.exe.decode(st.cache, tok)
+                        st.cache = cache
+                        self.ctxs.acc_density(st.ctx, mass, st.ctx.n_tokens)
+                        st.next_tok = st.sampler(logits)
+            n_stepped = sum(tok is not None for tok in out)
+            if n_stepped:
+                share = (time.perf_counter() - t1) / n_stepped
+                for st, tok in zip(sts, out):
+                    if tok is not None:
+                        st.t_infer += share
+            return out
 
     def _decode_round_paged(self, live: List[GenerationState],
                             fed: List[int]):
@@ -376,18 +387,24 @@ class LLMService:
         (no merge/split)."""
         pool = self.res.pool
         cs = self.exe.cs
-        pos = []
-        for st in live:
-            p = st.ctx.n_tokens - 1         # the just-emitted token
-            self.res.ensure_tail(st.ctx, p // cs)
-            pool.touch(st.ctx.cid)
-            pos.append(p)
-        pt16, pt8, qmask = pool.rows([st.ctx.cid for st in live])
-        pool.arenas, logits, mass = self.exe.paged_decode(
-            pool.arenas, fed, pos, pt16, pt8, qmask)
-        for i, st in enumerate(live):
-            self.ctxs.acc_density(st.ctx, mass[i], st.ctx.n_tokens)
-            st.next_tok = st.sampler(logits[i])
+        tel = self.telemetry
+        with tel.span("decode.prepare"):
+            pos = []
+            for st in live:
+                p = st.ctx.n_tokens - 1         # the just-emitted token
+                self.res.ensure_tail(st.ctx, p // cs)
+                pool.touch(st.ctx.cid)
+                pos.append(p)
+            pt16, pt8, qmask = pool.rows([st.ctx.cid for st in live])
+        with tel.span("decode.step"):
+            pool.arenas, logits, mass = self.exe.paged_decode(
+                pool.arenas, fed, pos, pt16, pt8, qmask)
+        with tel.span("decode.density"):
+            for i, st in enumerate(live):
+                self.ctxs.acc_density(st.ctx, mass[i], st.ctx.n_tokens)
+        with tel.span("decode.sample"):
+            for i, st in enumerate(live):
+                st.next_tok = st.sampler(logits[i])
 
     @requires_serialized
     def suspend_call(self, st: GenerationState):
@@ -397,10 +414,7 @@ class LLMService:
         sampled-but-unemitted token stays in the state, so resume
         continues the interrupted decode."""
         assert not (st.suspended or st.done)
-        t2 = time.perf_counter()
-        self.res.compress_and_swap_out(st.ctx, st.cache)
-        self.mem.reclaim(0, self.res.evict, locked=set())
-        st.t_swapout += time.perf_counter() - t2
+        self._swap_out(st)
         self._park(st)
         st.suspended = True
         st.n_preempts += 1
@@ -444,32 +458,40 @@ class LLMService:
         busy/record bookkeeping runs even if the swap-out fails, so an
         errored call never bricks its context."""
         ctx = st.ctx
-        try:
-            if not st.suspended:
-                t2 = time.perf_counter()
-                self.res.compress_and_swap_out(ctx, st.cache)
-                self.mem.reclaim(0, self.res.evict, locked=set())
-                st.t_swapout += time.perf_counter() - t2
-                self._park(st)
-        finally:
-            if st.slot is not None:     # park failed: free the slot
-                self.res.slots.release(ctx.cid)
-                st.slot = None
-            st.cache = None
-            st.done = True
-            ctx.busy -= 1
-            self.total_calls += 1
-            self._t_switch_sum += st.t_switch
-            self.records.append({
-                "ctx": ctx.cid, "switch_s": st.t_switch,
-                "infer_s": st.t_infer + st.t_assemble,
-                "assemble_s": st.t_assemble,
-                "swapout_s": st.t_swapout,
-                "new_tokens": st.prompt_len + len(st.generated),
-                "n_preempts": st.n_preempts,
-                "mem_used": self.mem.used,
-            })
+        with self.telemetry.span("service.finish_call", ctx=ctx.cid):
+            try:
+                if not st.suspended:
+                    self._swap_out(st)
+                    self._park(st)
+            finally:
+                if st.slot is not None:     # park failed: free the slot
+                    self.res.slots.release(ctx.cid)
+                    st.slot = None
+                st.cache = None
+                st.done = True
+                ctx.busy -= 1
+                self.total_calls += 1
+                self._t_switch_sum += st.t_switch
+                self.records.append({
+                    "ctx": ctx.cid, "switch_s": st.t_switch,
+                    "infer_s": st.t_infer + st.t_assemble,
+                    "assemble_s": st.t_assemble,
+                    "new_tokens": st.prompt_len + len(st.generated),
+                    "n_preempts": st.n_preempts,
+                    "mem_used": self.mem.used,
+                })
+                self.telemetry.count("calls_finished")
         return st.generated
+
+    @requires_serialized
+    def _swap_out(self, st: GenerationState):
+        """Compress + AoT swap-out of the generation's context, then the
+        reclaim that brings memory back under the budget."""
+        tel, cid = self.telemetry, st.ctx.cid
+        with tel.span("residency.swap_out", ctx=cid):
+            self.res.compress_and_swap_out(st.ctx, st.cache)
+        with tel.span("residency.reclaim", ctx=cid):
+            self.mem.reclaim(0, self.res.evict, locked=set())
 
     @requires_serialized
     def _switch_in(self, st: GenerationState):
@@ -492,7 +514,8 @@ class LLMService:
             st.t_switch += time.perf_counter() - t0
         else:
             try:
-                cache, t_sw = self.res.switch_in(ctx)
+                with self.telemetry.span("residency.switch_in", ctx=ctx.cid):
+                    cache, t_sw = self.res.switch_in(ctx)
             except BaseException:
                 self.res.slots.release(ctx.cid)
                 st.slot = None
@@ -526,30 +549,31 @@ class LLMService:
     @requires_serialized
     def _condense(self, ctx: Context, keep: int):
         """Context overflow: re-encode the recent tail at [0, keep)."""
-        tail = self.ctxs.reset_for_condense(ctx, keep, self.exe.cs)
-        # the rebuilt state invalidates any parked slot cache of THIS ctx
-        self._drop_reuse(ctx.cid)
-        self.res.slots.release(ctx.cid)
-        ctx.tokens[:len(tail)] = tail
-        if self.paged:
-            # the rebuilt state also invalidates every page this ctx held
-            pool = self.res.pool
-            pool.drop(ctx.cid)
-            self.res.ensure_extend_range(ctx, 0,
-                                         (len(tail) - 1) // self.exe.cs)
-            pt16, pt8, qmask = pool.rows([ctx.cid])
-            pool.arenas, _, dens = self.exe.paged_extend(
-                pool.arenas, np.asarray(tail, np.int32), 0,
-                pt16, pt8, qmask)
-            self.ctxs.acc_density(ctx, dens, len(tail))
-            ctx.n_tokens = len(tail)
-            self.res.compress_and_swap_out(ctx, None)
-        else:
-            cache = self.exe.fresh_cache(0)
-            cache, _, dens = self.exe.extend(cache, tail, 0)
-            self.ctxs.acc_density(ctx, dens, len(tail))
-            ctx.n_tokens = len(tail)
-            self.res.compress_and_swap_out(ctx, cache)
+        with self.telemetry.span("service.condense", ctx=ctx.cid):
+            tail = self.ctxs.reset_for_condense(ctx, keep, self.exe.cs)
+            # the rebuilt state invalidates any parked slot cache of THIS ctx
+            self._drop_reuse(ctx.cid)
+            self.res.slots.release(ctx.cid)
+            ctx.tokens[:len(tail)] = tail
+            if self.paged:
+                # the rebuilt state also invalidates every page this ctx held
+                pool = self.res.pool
+                pool.drop(ctx.cid)
+                self.res.ensure_extend_range(ctx, 0,
+                                             (len(tail) - 1) // self.exe.cs)
+                pt16, pt8, qmask = pool.rows([ctx.cid])
+                pool.arenas, _, dens = self.exe.paged_extend(
+                    pool.arenas, np.asarray(tail, np.int32), 0,
+                    pt16, pt8, qmask)
+                self.ctxs.acc_density(ctx, dens, len(tail))
+                ctx.n_tokens = len(tail)
+                self.res.compress_and_swap_out(ctx, None)
+            else:
+                cache = self.exe.fresh_cache(0)
+                cache, _, dens = self.exe.extend(cache, tail, 0)
+                self.ctxs.acc_density(ctx, dens, len(tail))
+                ctx.n_tokens = len(tail)
+                self.res.compress_and_swap_out(ctx, cache)
 
     @requires_serialized
     def profile_pipeline(self, n_points: Tuple[int, ...] = (1, 2, 4)):

@@ -29,6 +29,7 @@ from repro.analysis.markers import requires_lock
 from repro.analysis.runtime import witness_lock
 from repro.core.faults import (FAULTS, ChunkCorruptError, SwapTimeoutError,
                                with_retries)
+from repro.core.telemetry import Telemetry
 
 Key = Tuple[int, Any]              # (ctx_id, chunk_idx | "state")
 
@@ -82,10 +83,12 @@ def sweep_tmp_files(root: str) -> int:
 
 class DiskStore:
     """Pickle-per-key chunk store with byte accounting and a checksummed
-    file envelope."""
+    file envelope.  ``read``/``write`` run in ``store.read``/
+    ``store.write`` spans of ``telemetry``."""
 
-    def __init__(self, root: str):
+    def __init__(self, root: str, telemetry: Optional[Telemetry] = None):
         self.root = root
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
         os.makedirs(root, exist_ok=True)
         self.tmp_swept = sweep_tmp_files(root)
         self.delete_errors = 0
@@ -98,31 +101,34 @@ class DiskStore:
 
     def write(self, key: Key, obj: Any) -> int:
         from repro.core.restore import _throttle, count_io
-        FAULTS.check("disk.write", key)
-        raw = seal_blob(pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL))
-        tmp = self._path(key) + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(raw)
-        action = FAULTS.corrupt_action(key)
-        if action is not None:
-            from repro.core.faults import corrupt_file
-            corrupt_file(tmp, action)
-        os.replace(tmp, self._path(key))          # atomic
-        FAULTS.note_write_ok(key)
-        count_io("write", len(raw))
-        _throttle(len(raw))
-        with self._lock:
-            self._bytes[key] = len(raw)
-        return len(raw)
+        with self.telemetry.span("store.write"):
+            FAULTS.check("disk.write", key)
+            raw = seal_blob(pickle.dumps(obj,
+                                         protocol=pickle.HIGHEST_PROTOCOL))
+            tmp = self._path(key) + ".tmp"
+            with open(tmp, "wb") as f:
+                f.write(raw)
+            action = FAULTS.corrupt_action(key)
+            if action is not None:
+                from repro.core.faults import corrupt_file
+                corrupt_file(tmp, action)
+            os.replace(tmp, self._path(key))          # atomic
+            FAULTS.note_write_ok(key)
+            count_io("write", len(raw))
+            _throttle(len(raw))
+            with self._lock:
+                self._bytes[key] = len(raw)
+            return len(raw)
 
     def read(self, key: Key) -> Any:
         from repro.core.restore import _throttle, count_io
-        FAULTS.check("disk.read", key)
-        with open(self._path(key), "rb") as f:
-            raw = f.read()
-        count_io("read", len(raw))
-        _throttle(len(raw))
-        return pickle.loads(open_blob(raw, f"state {key}"))
+        with self.telemetry.span("store.read"):
+            FAULTS.check("disk.read", key)
+            with open(self._path(key), "rb") as f:
+                raw = f.read()
+            count_io("read", len(raw))
+            _throttle(len(raw))
+            return pickle.loads(open_blob(raw, f"state {key}"))
 
     def delete(self, key: Key):
         try:

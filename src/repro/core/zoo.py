@@ -5,7 +5,8 @@ One process, MANY model families: each family gets its own
 capability-driven by the family's ``KVSpec``), but every member shares
 ONE substrate — a single ``DiskStore``/``AsyncSwapper`` swap tier, a
 single ``LCTRUQueue`` eviction order, a single ``MemoryManager`` byte
-budget, one context-id space, and one records stream.  The zoo exposes
+budget, one context-id space, one records stream, and one ``Telemetry``
+span registry.  The zoo exposes
 the exact service surface ``ServiceRouter`` drives (``newLLMCtx`` /
 ``begin_call`` / ``decode_step_batch`` / ``finish_call`` / ...), so a
 router scheduling a dense chat model, an MLA long-context model and a
@@ -36,6 +37,7 @@ from repro.core.context_store import LLMCtxStub
 from repro.core.lifecycle import LCTRUQueue, MemoryManager
 from repro.core.service import GenerationState, LLMSConfig, LLMService
 from repro.core.swap import AsyncSwapper, DiskStore
+from repro.core.telemetry import Telemetry
 
 
 class _ZooResView:
@@ -67,7 +69,8 @@ class ZooService:
         cfgs = [cfg for _, _, cfg in members.values()]
         first = cfgs[0]
         root = swap_dir or tempfile.mkdtemp(prefix="llms_zoo_")
-        self.store = DiskStore(root)
+        self.telemetry = Telemetry()
+        self.store = DiskStore(root, self.telemetry)
         self.swapper = AsyncSwapper(self.store, retries=first.io_retries,
                                     retry_base_s=first.io_retry_base_s)
         self.queue = LCTRUQueue(lru_only=not any(c.use_lctru for c in cfgs))
@@ -81,7 +84,8 @@ class ZooService:
             svc = LLMService(model, params, cfg, store=self.store,
                              swapper=self.swapper, queue=self.queue,
                              mem=self.mem, cid_alloc=self._alloc_cid,
-                             records=self.records)
+                             records=self.records,
+                             telemetry=self.telemetry)
             svc.res.route_evict = self._route_evict
             self.members[fam] = svc
         self.default_family = next(iter(self.members))
@@ -235,12 +239,13 @@ class ZooService:
             "zoo_families": tuple(self.members),
         }
         # fault stats: engine-local detect/recover counters sum across
-        # members; swapper/store/global-injection counters are SHARED
+        # members; swapper/store/global-injection counters and the
+        # shared registry's (chunks_recovered_recompute) are SHARED
         # substrate — take them once (summing would multiply by the
         # member count)
         fault_sum = next(iter(self.members.values())).res.fault_stats()
         for k in ("degraded_entries", "degraded_exits",
-                  "chunks_recovered_recompute", "chunks_corrupt_detected",
+                  "chunks_corrupt_detected",
                   "io_errors_detected", "evict_dropped", "recover_failed"):
             fault_sum[k] = sum(m.res.fault_stats()[k]
                                for m in self.members.values())

@@ -77,8 +77,9 @@ def build_report(spec, *, router_stats: Dict[str, Any],
                  tokens_sha_by_app: Optional[Dict[str, str]] = None
                  ) -> Dict[str, Any]:
     """One scenario run -> the report dict written to
-    BENCH_scenarios.json.  Everything except ``wall_s`` is
-    deterministic in (scenario, seed) and portable across machines."""
+    BENCH_scenarios.json.  Everything except ``wall_s`` and the span
+    timings under ``telemetry`` is deterministic in (scenario, seed)
+    and portable across machines."""
     toks = int(router_stats.get("decoded_tokens", 0))
     moved = int(io_read) + int(io_written)
     report: Dict[str, Any] = {
@@ -99,7 +100,10 @@ def build_report(spec, *, router_stats: Dict[str, Any],
         "io": {"disk_bytes_read": int(io_read),
                "disk_bytes_written": int(io_written),
                "bytes_moved_per_token": moved / max(1, toks)},
-        "router": router_stats,
+        "router": {k: v for k, v in router_stats.items()
+                   if k != "telemetry"},
+        # NOT gated: spans on the machine's clock
+        "telemetry": router_stats.get("telemetry"),
         "service": {k: svc_stats.get(k) for k in (
             "total_calls", "switch_mean_s", "switch_p99_s",
             "switch_total_s", "mem_used", "disk_bytes",
@@ -176,13 +180,14 @@ def gate_metrics(report: Dict[str, Any]) -> Dict[str, Any]:
 
 def deterministic_view(report: Dict[str, Any]) -> Dict[str, Any]:
     """The subset of a report that must be IDENTICAL across same-seed
-    runs.  ``wall_s`` is machine time; the ``service`` section carries
-    wall-clock switch timings and the disk store's residual byte count
-    (async swap-out completion order is thread-scheduling dependent).
-    Everything else — event log hash, virtual-time QoS, queue depth,
-    pool counters, io deltas — is a pure function of the seed."""
+    runs.  ``wall_s`` and ``telemetry`` are machine time; the
+    ``service`` section carries wall-clock switch timings and the disk
+    store's residual byte count (async swap-out completion order is
+    thread-scheduling dependent).  Everything else — event log hash,
+    virtual-time QoS, queue depth, pool counters, io deltas — is a pure
+    function of the seed."""
     return {k: v for k, v in report.items()
-            if k not in ("wall_s", "service")}
+            if k not in ("wall_s", "service", "telemetry")}
 
 
 def write_bench(path: str, doc: Dict[str, Any]):
