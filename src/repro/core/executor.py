@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.chunks import ChunkCodec, WholeStateCodec
 from repro.core.telemetry import Telemetry
+from repro.models.common import paged_decode_in_place
 from repro.models.kvspec import LAYOUT_MIXED, LAYOUT_WINDOW
 
 Array = jax.Array
@@ -106,6 +107,16 @@ _ACTIVE_FEED = None
 
 def _feed_fetch(layer):
     return _ACTIVE_FEED.fetch(layer)
+
+
+def paged_decode_program(model, window: int = 0, n_sinks: int = 0):
+    """The jitted paged decode round, density on.  The layer unroll
+    mirrors the old batched-decode entry: XLA CPU's rolled scan shuffles
+    the gathered multi-row cache every layer and dominates the step."""
+    L = model.cfg.n_layers
+    return jax.jit(functools.partial(
+        model.decode_paged, want_density=True, unroll=L if L <= 48 else 1,
+        window=window, n_sinks=n_sinks))
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -237,7 +248,6 @@ class ModelExecutor:
             pcached = _jit_cache_get(pk)
             if pcached is None:
                 cw = dict(window=cfg.window, n_sinks=cfg.n_sinks)
-                L = mc.n_layers
                 leaves = tuple(self.codec.leaves)
                 dims = dict(self.leaf_dims)
                 cs, nl = self.cs, self.n_layers
@@ -285,12 +295,7 @@ class ModelExecutor:
                     return out
 
                 pcached = {
-                    # unroll mirrors the old batched-decode entry: XLA
-                    # CPU's rolled scan shuffles the gathered multi-row
-                    # cache every layer and dominates the step
-                    "decode": jax.jit(functools.partial(
-                        model.decode_paged, want_density=True,
-                        unroll=L if L <= 48 else 1, **cw)),
+                    "decode": paged_decode_program(model, **cw),
                     "extend": jax.jit(functools.partial(
                         model.extend_paged, want_density=True, **cw)),
                     "admit16": jax.jit(admit16),
@@ -407,7 +412,10 @@ class ModelExecutor:
         by ``toks[i]`` at its own position ``pos[i]``, batch-bucketed.
         Pad rows get the all-zero page-table row (scratch page) and are
         sliced off the outputs.  -> (arenas', logits [n, V],
-        density-mass [n, S])."""
+        density-mass [n, S]).  A round that reads its pages in place
+        counts one ``decode.inplace_rounds`` and, in
+        ``decode.pages_attended``, the pages its real rows' attention
+        spans (``pos // cs + 1`` a row, the new token's page included)."""
         n = len(toks)
         nb = next(b for b in self.batch_buckets if b >= n)
         toks_b = np.zeros((nb, 1), np.int32)
@@ -423,6 +431,10 @@ class ModelExecutor:
             pt8_b[:n] = pt8
             qmask_b = np.zeros((nb, C), bool)
             qmask_b[:n] = qmask
+        if paged_decode_in_place(arenas, pt8):
+            self.tel.count("decode.inplace_rounds")
+            self.tel.count("decode.pages_attended",
+                           int(sum(p // self.cs + 1 for p in pos)))
         with self.tel.span("exe.paged_decode"):
             arenas, logits, mass = self.paged_decode_fn(
                 self.params, jnp.asarray(toks_b), arenas,

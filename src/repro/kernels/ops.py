@@ -9,6 +9,7 @@ pure-jnp oracle (ref.py) elsewhere.  ``force`` overrides for testing:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -97,3 +98,21 @@ def decode_mqattn(q: Array, k: Array, v: Array, k_q: Array, v_q: Array,
     return kdq.decode_mqattn(q, k, v, k_q, v_q, k_scale, v_scale,
                              quant_mask, n_valid, window, n_sinks,
                              interpret=(mode == "interpret"))
+
+
+# --------------------------------------------------------------------- #
+# paged decode attention over the bf16 page arena, pages read in place
+# --------------------------------------------------------------------- #
+def paged_decode_attn(q: Array, k_arena: Array, v_arena: Array, layer,
+                      pt: Array, pos: Array, window: int = 0,
+                      n_sinks: int = 0):
+    """No jnp oracle: the gather path (``models/common.py``
+    ``paged_cache_view``) is the CPU implementation.  The kernel is
+    chosen by the platform the program is lowered for: compiled on a
+    TPU, interpreted elsewhere."""
+    from repro.kernels import paged_attn as kpa
+    call = functools.partial(kpa.paged_decode_attn, q, k_arena, v_arena,
+                             layer, pt, pos, window, n_sinks)
+    return jax.lax.platform_dependent(
+        tpu=lambda: call(interpret=False),
+        default=lambda: call(interpret=True))

@@ -11,13 +11,10 @@ positions are masked to exactly zero weight by the attention mask
 before they can contribute).
 
 This is the blocked-jnp CPU mirror: XLA lowers the advanced-indexing
-gather to a block copy per (layer, page) that fuses with the
-downstream attention read.  On TPU the natural implementation is a
-Pallas kernel that keeps the arena in HBM and DMA-gathers the page
-list into VMEM tiles ahead of the attention loop (the MNN-LLM
-layout); the downstream mixed-decode attention already dispatches to
-``kernels.ops.decode_mqattn`` there, so only the gather itself would
-move into Pallas.
+gather to a block copy per (layer, page).  On a TPU that view costs
+several writes of a cache-sized buffer a round, so bf16 decode there
+reads its pages in place instead (``kernels/paged_attn.py``, chosen by
+``models/common.py::paged_decode_in_place``).
 """
 from __future__ import annotations
 

@@ -601,6 +601,72 @@ def paged_cache_view(arenas, leaves, pt16, pt8=None, quant_chunks=None,
     return cache
 
 
+def on_tpu() -> bool:
+    """The backend check of ``paged_decode_in_place`` (tests steer it)."""
+    return jax.default_backend() == "tpu"
+
+
+def paged_decode_in_place(arenas, pt8) -> bool:
+    """Whether a paged decode round reads its K/V pages in place
+    (``paged_decode_attention``) instead of through
+    ``paged_cache_view``.  The dense view is the round's largest cost on
+    the chip: the view and its relayouts are written several times over
+    for every token.  A Pallas operand must keep XLA's default layout,
+    which the compiler gives a bf16 arena only where a page's
+    ``(KV, hd)`` rows are whole tiles (``hd`` a multiple of 128); at
+    ``hd`` 64 it moves the page axis minor, and a kernel there would pay
+    a whole-arena relayout a call.  Quant-resident pages (``pt8``) have
+    no kernel here."""
+    return (on_tpu() and pt8 is None
+            and arenas["k16"].shape[-1] % 128 == 0)
+
+
+def paged_decode_attention(q: Array, k_new: Array, v_new: Array,
+                           k_arena: Array, v_arena: Array, layer, pt16,
+                           pos, window: int = 0, n_sinks: int = 0,
+                           want_density: bool = False):
+    """``decode_attention`` for one layer of a paged round without a
+    dense view.  q (B,1,H,hd); k_new/v_new (B,1,KV,hd) the round's own
+    token at ``pos`` (B,); arenas (L, P, cs, KV, hd) before the round.
+    The kernel attends the cached positions ``< pos`` in place; the new
+    token joins its softmax here (the flash-decoding combine).
+    -> out (B,1,H,hd)[, mass (B, C*cs) f32: per-key probability summed
+    over heads / H, the new token's at ``pos``]."""
+    from repro.kernels import ops as kops
+    B, _, H, hd = q.shape
+    KV = k_new.shape[2]
+    G = H // KV
+    k_new = k_new.astype(k_arena.dtype)
+    v_new = v_new.astype(v_arena.dtype)
+    acc, m, l, s = kops.paged_decode_attn(
+        q[:, 0], k_arena, v_arena, layer, pt16, pos, window, n_sinks)
+    scale = 1.0 / np.sqrt(hd)
+    qg = q[:, 0].reshape(B, KV, G, hd)
+    s_new = jnp.einsum("bngd,bnd->bng", qg, k_new[:, 0],
+                       preferred_element_type=jnp.float32
+                       ).reshape(B, H) * scale
+    m_f = jnp.maximum(m, s_new)
+    a = jnp.exp(m - m_f)
+    e_new = jnp.exp(s_new - m_f)
+    l_f = l * a + e_new
+    v_h = jnp.repeat(v_new[:, 0].astype(jnp.float32), G, axis=1)
+    out = (acc * a[..., None] + e_new[..., None] * v_h) / l_f[..., None]
+    out = out.astype(q.dtype)[:, None]
+    if not want_density:
+        return out
+    S = s.shape[-1]
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (S,), 0)[None, :]
+    p_b = pos[:, None]
+    valid = k_pos < p_b
+    if window > 0:
+        valid = valid & ((k_pos > p_b - window) | (k_pos < n_sinks))
+    p = jnp.exp(s - m_f[..., None]) / l_f[..., None]
+    mass = jnp.sum(jnp.where(valid[:, None], p, 0.0), axis=1)
+    mass = mass + jnp.where(k_pos == p_b, jnp.sum(e_new / l_f, axis=1
+                                                  )[:, None], 0.0)
+    return out, mass / H
+
+
 # --------------------------------------------------------------------- #
 # FFN
 # --------------------------------------------------------------------- #

@@ -472,7 +472,9 @@ class DenseModel(ModelBase):
     # layout the slot entries consume, run the unchanged decode_step /
     # recompute body, and scatter only the newly written tokens back
     # into their bf16 tail pages — so slots are views into the pool and
-    # the emitted tokens are bit-identical to the slot-cache path.
+    # the emitted tokens are bit-identical to the slot-cache path.  On
+    # the chip, decode reads bf16 pages in place instead where
+    # ``C.paged_decode_in_place`` says so (same mask, f32 online softmax).
     # ------------------------------------------------------------------ #
     def decode_paged(self, params, tokens, arenas, pt16, pt8, quant_chunks,
                      pos, window: int = 0, n_sinks: int = 0,
@@ -483,26 +485,72 @@ class DenseModel(ModelBase):
         decode positions.  -> (arenas', logits[, mass]).  Batch
         membership is carried entirely by the page-table rows: joining
         or leaving the batch changes only pt16/pt8/pos, never copies
-        cache state (no merge/split)."""
+        cache state (no merge/split).  Where
+        ``C.paged_decode_in_place`` holds, the layers read the pages in
+        place and no dense view is built; otherwise they run
+        ``decode_step`` over ``paged_cache_view``."""
         cs = arenas["k16"].shape[2]
-        cache = C.paged_cache_view(arenas, ("k", "v"), pt16, pt8,
-                                   quant_chunks, pos)
-        out = self.decode_step(params, tokens, cache, window, n_sinks,
-                               want_density, unroll)
-        mass = None
-        if want_density:
-            out, mass = out
         rows = jnp.arange(tokens.shape[0])
+        if C.paged_decode_in_place(arenas, pt8):
+            logits, new_kv, mass = self._decode_in_place(
+                params, tokens, arenas, pt16, pos, window, n_sinks,
+                want_density, unroll)
+        else:
+            cache = C.paged_cache_view(arenas, ("k", "v"), pt16, pt8,
+                                       quant_chunks, pos)
+            out = self.decode_step(params, tokens, cache, window, n_sinks,
+                                   want_density, unroll)
+            mass = None
+            if want_density:
+                out, mass = out
+            logits = out.logits
+            new_kv = {n: out.cache[n][:, rows, pos] for n in ("k", "v")}
+        # the round's new tokens, (L, B, KV, hd) a leaf, in one scatter
         pages = pt16[rows, pos // cs]
         offs = pos % cs
         new_arenas = dict(arenas)
         for n in ("k", "v"):
-            val = out.cache[n][:, rows, pos]            # (L, B, KV, hd)
             new_arenas[n + "16"] = arenas[n + "16"].at[
-                :, pages, offs].set(val)
+                :, pages, offs].set(new_kv[n])
         if want_density:
-            return new_arenas, out.logits, mass
-        return new_arenas, out.logits
+            return new_arenas, logits, mass
+        return new_arenas, logits
+
+    def _decode_in_place(self, params, tokens, arenas, pt16, pos, window,
+                         n_sinks, want_density, unroll):
+        """``decode_step``'s layer scan over pages read in place
+        (``C.paged_decode_attention``): the scan's ``ys`` carry only the
+        round's new K/V and the mass, never a cache.
+        -> (logits, {"k", "v"}: (L, B, KV, hd), mass (B, S) | None)."""
+        cfg = self.cfg
+        x = C.constrain_batch(
+            params["embed"][tokens].astype(jnp.bfloat16))  # (B, 1, d)
+        positions = pos[:, None]
+        k_ar, v_ar = arenas["k16"], arenas["v16"]
+
+        def body(x, layer_in):
+            pl, layer = layer_in
+            h = C.rms_norm(x, pl["ln_attn"], cfg.norm_eps)
+            q, k, v = self._qkv(pl, h)
+            q, k = self._rope(q, k, positions)
+            q, k, v = (C.constrain_batch(t) for t in (q, k, v))
+            out = C.paged_decode_attention(
+                q, k, v, k_ar, v_ar, layer, pt16, pos, window=window,
+                n_sinks=n_sinks, want_density=want_density)
+            ys = {"k": k[:, 0].astype(k_ar.dtype),
+                  "v": v[:, 0].astype(v_ar.dtype)}
+            if want_density:
+                out, ys["mass"] = out
+            x = x + out.reshape(*x.shape[:2], -1) @ pl["wo"]
+            x = C.constrain_batch(self._ffn(pl, x))
+            return x, ys
+
+        xs = (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32))
+        x, ys = jax.lax.scan(body, x, xs, unroll=max(1, int(unroll)))
+        x = C.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        logits = (x[:, 0] @ self.head_weight(params)).astype(jnp.float32)
+        mass = jnp.mean(ys["mass"], axis=0) if want_density else None
+        return logits, ys, mass
 
     def extend_paged(self, params, miss_tokens, miss_pos, arenas, pt16,
                      pt8, quant_chunks, seq_len, window: int = 0,

@@ -9,6 +9,7 @@ import sys
 import tempfile
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -248,6 +249,56 @@ def test_served_path_spans_and_counters(monkeypatch):
     for name, parents in nesting.exe_parents:
         assert any(p.startswith(("service.", "router.")) for p in parents), \
             (name, parents)
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel", "gather"])
+def test_in_place_decode_counters(in_place, monkeypatch):
+    """``decode.inplace_rounds`` and ``decode.pages_attended`` count
+    what the rule chose: on the kernel's path (the rule's backend check
+    steered, the kernel interpreted) every round and the pages its rows'
+    attention spans; on the gather path nothing."""
+    import collections
+    import dataclasses
+
+    from repro.configs import get_config, reduced
+    from repro.core import executor as executor_mod
+    from repro.models import common
+    from repro.models.registry import build_model
+
+    monkeypatch.setattr(common, "on_tpu", lambda: in_place)
+    # the jitted programs are cached by model, not by the rule's answer
+    monkeypatch.setattr(executor_mod, "_JIT_CACHE",
+                        collections.OrderedDict())
+    cfg = dataclasses.replace(reduced(get_config("qwen2.5-14b")),
+                              n_layers=2, head_dim=128)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    sc = LLMSConfig(policy="llms", max_ctx_len=128, chunk_tokens=16,
+                    memory_budget=10_000_000, decode_batch=2,
+                    swap_dir=tempfile.mkdtemp())
+    svc = LLMService(model, params, sc)
+    router = ServiceRouter(svc, predict=True, slice_steps=2)
+    exe = svc.exe
+    rounds = []
+    decode = exe.paged_decode
+
+    def recorded(arenas, toks, pos, *a):
+        rounds.append(list(pos))
+        return decode(arenas, toks, pos, *a)
+    monkeypatch.setattr(exe, "paged_decode", recorded)
+    with svc:
+        calls = serve(cfg, router, n_ctx=2, calls=2)
+        counters = router.stats()["telemetry"]["counters"]
+    assert [len(c) for c in calls] == [5] * 4
+    assert rounds
+    if in_place:
+        assert counters["decode.inplace_rounds"] == len(rounds)
+        assert counters["decode.pages_attended"] == sum(
+            p // 16 + 1 for pos in rounds for p in pos)
+    else:
+        assert "decode.inplace_rounds" not in counters
+        assert "decode.pages_attended" not in counters
 
 
 def test_reset_stats_resets_telemetry():

@@ -6,7 +6,9 @@ kernel the chip's compiler refuses fails here at no chip time.
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and every test
 worker imports every test file."""
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,3 +91,43 @@ def test_decode_mqattn_compiles(one_chip, B, S):
 def test_decode_qattn_compiles(one_chip):
     assert_kernel_compiles(kdq.decode_qattn,
                            *_decode_shapes(one_chip, 4, 2048, mixed=False))
+
+
+def test_paged_decode_program_reads_pages_in_place(one_chip, monkeypatch):
+    """The executor's paged decode program at ``qwen2.5-14b.decode``'s
+    shapes takes the in-place kernel: no buffer of the dense ``(L, B, S,
+    KV, hd)`` view anywhere in the program, and its temporaries stay
+    small (2.35 GB with the view)."""
+    import json
+    from pathlib import Path
+
+    from chipbench.configs import dense
+    from repro.configs.base import ModelConfig
+    from repro.core.executor import paged_decode_program
+    from repro.models import common
+    from repro.models.registry import build_model
+
+    root = Path(__file__).resolve().parent.parent / "chipbench"
+    cfg = json.loads((root / "configs" / "qwen2.5-14b.json").read_text())
+    sv = json.loads((root / "traffic" / "decode.json").read_text())["service"]
+    model = build_model(ModelConfig(**dense.program_config(cfg)))
+    mc = model.cfg
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cs, B, P = T, sv["decode_batch"], sv["pool_pages_16"]
+    C = 2 * sv["max_ctx"] // cs     # window plus the largest prompt bucket
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    arena = s((mc.n_layers, P, cs, mc.n_kv_heads, mc.head_dim), jnp.bfloat16)
+    compiled = paged_decode_program(model).lower(
+        params, s((B, 1), jnp.int32), {"k16": arena, "v16": arena},
+        s((B, C), jnp.int32), None, None, s((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.25e9
+    view = mc.n_layers * B * C * cs * mc.n_kv_heads * mc.head_dim
+    sizes = {math.prod(int(d) for d in dims.split(","))
+             for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text)}
+    assert view not in sizes
