@@ -8,10 +8,10 @@ via the dry-run's ShapeDtypeStructs).
 """
 from __future__ import annotations
 
+import dataclasses
 
 from repro.configs.base import (
     EncoderConfig,
-    MLAConfig,
     ModelConfig,
     MoEConfig,
     RGLRUConfig,
@@ -71,14 +71,19 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     if cfg.family == "moe":
         kw["moe"] = MoEConfig(n_experts=4, top_k=cfg.moe.top_k if cfg.moe.top_k <= 2 else 2,
                               d_expert=96, n_shared=cfg.moe.n_shared,
-                              d_shared=96 if cfg.moe.d_shared else 0,
-                              capacity_factor=2.0, group_size=32)
+                              d_shared=96 if cfg.moe.d_shared else 0)
     if cfg.family == "mla_moe":
-        kw["moe"] = MoEConfig(n_experts=4, top_k=2, d_expert=96, n_shared=2,
-                              d_shared=96, capacity_factor=2.0, group_size=32)
-        kw["mla"] = MLAConfig(kv_lora_rank=32, q_lora_rank=0,
-                              qk_nope_head_dim=16, qk_rope_head_dim=8,
-                              v_head_dim=16)
+        # a dense first layer, then dropless top-2 of 8 routed experts,
+        # and YaRN at a small factor
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, n_experts=8, top_k=2, d_expert=96, n_shared=1,
+            d_shared=96, first_dense=min(cfg.moe.first_dense, 1),
+            d_dense_ff=128)
+        kw["mla"] = dataclasses.replace(
+            cfg.mla, kv_lora_rank=32, q_lora_rank=0, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16,
+            factor=min(cfg.mla.factor, 4.0),
+            original_max_position_embeddings=64)
     if cfg.family == "rglru_hybrid":
         kw["n_layers"] = 5  # rec,rec,attn,rec,rec
         kw["rglru"] = RGLRUConfig(lru_width=64, conv_width=4, window=64,

@@ -28,29 +28,39 @@ Family = Literal[
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts FFN configuration (dropping/capacity dispatch)."""
+    """Mixture-of-experts FFN configuration.  Routing is dropless: each
+    token takes the greedy top-k of a softmax over the router's logits,
+    weighted by those scores as they are (not renormalised, not
+    scaled)."""
 
     n_experts: int
     top_k: int
     d_expert: int                 # per-expert FFN hidden size
     n_shared: int = 0             # number of always-on shared experts
     d_shared: int = 0             # hidden size of the fused shared expert
-    capacity_factor: float = 1.25
-    # tokens are dispatched in groups of this size; a key perf lever --
-    # smaller groups shrink the one-hot dispatch tensors (see DESIGN.md)
-    group_size: int = 1024
-    router_aux_weight: float = 0.01
+    # the first ``first_dense`` layers run a dense SwiGLU MLP of width
+    # ``d_dense_ff`` in place of the experts (DeepSeek's
+    # ``first_k_dense_replace``)
+    first_dense: int = 0
+    d_dense_ff: int = 0
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """DeepSeek-V2 Multi-head Latent Attention."""
+    """DeepSeek-V2 Multi-head Latent Attention, with YaRN rope scaling
+    of the roped part (``factor`` 1 is plain rope)."""
 
     kv_lora_rank: int = 512
     q_lora_rank: int = 0          # 0 => full-rank Q projection
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -211,12 +221,10 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         expert = 3 * d * moe.d_expert
         shared = 3 * d * moe.d_shared if moe.d_shared else 0
         router = d * moe.n_experts
-        per_layer = attn + moe.n_experts * expert + shared + router
-        total += cfg.n_layers * per_layer
-        if active_only:
-            active_per_layer = attn + moe.top_k * expert + shared + router
-            return emb + head + cfg.n_layers * active_per_layer
-        return total
+        n_moe = cfg.n_layers - moe.first_dense
+        total += moe.first_dense * (attn + 3 * d * moe.d_dense_ff)
+        k = moe.top_k if active_only else moe.n_experts
+        return total + n_moe * (attn + k * expert + shared + router)
 
     if cfg.family == "moe":
         assert cfg.moe is not None

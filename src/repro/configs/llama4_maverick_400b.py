@@ -28,8 +28,6 @@ CONFIG = ModelConfig(
         d_expert=8192,
         n_shared=1,
         d_shared=8192,
-        capacity_factor=1.25,
-        group_size=1024,
     ),
     source="hf:meta-llama/Llama-4-Scout-17B-16E; unverified",
 )

@@ -412,10 +412,15 @@ class ModelExecutor:
         by ``toks[i]`` at its own position ``pos[i]``, batch-bucketed.
         Pad rows get the all-zero page-table row (scratch page) and are
         sliced off the outputs.  -> (arenas', logits [n, V],
-        density-mass [n, S]).  A round that reads its pages in place
-        counts one ``decode.inplace_rounds`` and, in
+        density-mass [n, S]).  Every round counts, in
         ``decode.pages_attended``, the pages its real rows' attention
-        spans (``pos // cs + 1`` a row, the new token's page included)."""
+        spans (``pos // cs + 1`` a row, the new token's page included);
+        one that reads its pages in place also counts one
+        ``decode.inplace_rounds``.  A routed model's round counts one
+        ``moe.decode_rounds`` and adds to ``moe.experts_hit`` the
+        distinct experts its real rows route to, summed over the routed
+        layers (from the expert ids the program returns, fetched with
+        the logits)."""
         n = len(toks)
         nb = next(b for b in self.batch_buckets if b >= n)
         toks_b = np.zeros((nb, 1), np.int32)
@@ -433,15 +438,20 @@ class ModelExecutor:
             qmask_b[:n] = qmask
         if paged_decode_in_place(arenas, pt8):
             self.tel.count("decode.inplace_rounds")
-            self.tel.count("decode.pages_attended",
-                           int(sum(p // self.cs + 1 for p in pos)))
+        self.tel.count("decode.pages_attended",
+                       int(sum(p // self.cs + 1 for p in pos)))
         with self.tel.span("exe.paged_decode"):
-            arenas, logits, mass = self.paged_decode_fn(
+            arenas, *outs = self.paged_decode_fn(
                 self.params, jnp.asarray(toks_b), arenas,
                 jnp.asarray(pt16_b),
                 None if pt8_b is None else jnp.asarray(pt8_b),
                 None if qmask_b is None else jnp.asarray(qmask_b),
                 jnp.asarray(pos_b))
+        logits, mass, *experts = jax.device_get(outs)
+        if experts:
+            self.tel.count("moe.decode_rounds")
+            self.tel.count("moe.experts_hit", sum(
+                len(np.unique(ids[:n])) for ids in experts[0]))
         return (arenas, np.asarray(logits)[:n],
                 np.asarray(mass, np.float64)[:n])
 

@@ -62,6 +62,12 @@ class PrefillOut(NamedTuple):
 class DecodeOut(NamedTuple):
     logits: Array                  # (B, vocab)
     cache: PyTree
+    # routed models: the expert ids each row chose in each routed
+    # layer, (L_moe, B, top_k) int32
+    experts: Optional[Array] = None
+    # where the family gives it: each sequence leaf's new row, (L, B,
+    # ...), the token this round wrote into ``cache`` at ``pos``
+    new_kv: Optional[PyTree] = None
 
 
 def cross_entropy(logits: Array, targets: Array, mask: Optional[Array] = None

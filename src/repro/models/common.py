@@ -615,9 +615,10 @@ def paged_decode_in_place(arenas, pt8) -> bool:
     which the compiler gives a bf16 arena only where a page's
     ``(KV, hd)`` rows are whole tiles (``hd`` a multiple of 128); at
     ``hd`` 64 it moves the page axis minor, and a kernel there would pay
-    a whole-arena relayout a call.  Quant-resident pages (``pt8``) have
-    no kernel here."""
-    return (on_tpu() and pt8 is None
+    a whole-arena relayout a call.  Quant-resident pages (``pt8``) and
+    arenas of other leaves than ``k``/``v`` (MLA's latent ``ckv``/
+    ``kpe``) have no kernel here."""
+    return (on_tpu() and pt8 is None and "k16" in arenas
             and arenas["k16"].shape[-1] % 128 == 0)
 
 

@@ -482,39 +482,44 @@ class DenseModel(ModelBase):
         """One [B, 1] decode round over the pool.  tokens (B, 1);
         pt16/pt8 (B, C) page-table rows; quant_chunks (B, C) bool (None
         with pt8=None outside quant-resident mode); pos (B,) per-slot
-        decode positions.  -> (arenas', logits[, mass]).  Batch
-        membership is carried entirely by the page-table rows: joining
-        or leaving the batch changes only pt16/pt8/pos, never copies
-        cache state (no merge/split).  Where
+        decode positions.  -> (arenas', logits[, mass][, experts]).
+        Batch membership is carried entirely by the page-table rows:
+        joining or leaving the batch changes only pt16/pt8/pos, never
+        copies cache state (no merge/split).  Where
         ``C.paged_decode_in_place`` holds, the layers read the pages in
         place and no dense view is built; otherwise they run
-        ``decode_step`` over ``paged_cache_view``."""
-        cs = arenas["k16"].shape[2]
+        ``decode_step`` over ``paged_cache_view``, for whatever cache
+        leaves the family's spec declares.  A family whose decode step
+        returns ``DecodeOut.experts`` also returns those (L_moe, B, k)
+        ids, pad rows included."""
+        leaves = self.kv_spec().seq_leaves
+        cs = arenas[leaves[0] + "16"].shape[2]
         rows = jnp.arange(tokens.shape[0])
+        experts = None
         if C.paged_decode_in_place(arenas, pt8):
             logits, new_kv, mass = self._decode_in_place(
                 params, tokens, arenas, pt16, pos, window, n_sinks,
                 want_density, unroll)
         else:
-            cache = C.paged_cache_view(arenas, ("k", "v"), pt16, pt8,
+            cache = C.paged_cache_view(arenas, leaves, pt16, pt8,
                                        quant_chunks, pos)
             out = self.decode_step(params, tokens, cache, window, n_sinks,
                                    want_density, unroll)
             mass = None
             if want_density:
                 out, mass = out
-            logits = out.logits
-            new_kv = {n: out.cache[n][:, rows, pos] for n in ("k", "v")}
-        # the round's new tokens, (L, B, KV, hd) a leaf, in one scatter
+            logits, experts, new_kv = out.logits, out.experts, out.new_kv
+            if new_kv is None:
+                new_kv = {n: out.cache[n][:, rows, pos] for n in leaves}
+        # the round's new tokens, (L, B, ...) a leaf, in one scatter
         pages = pt16[rows, pos // cs]
         offs = pos % cs
         new_arenas = dict(arenas)
-        for n in ("k", "v"):
+        for n in leaves:
             new_arenas[n + "16"] = arenas[n + "16"].at[
                 :, pages, offs].set(new_kv[n])
-        if want_density:
-            return new_arenas, logits, mass
-        return new_arenas, logits
+        outs = (new_arenas, logits) + ((mass,) if want_density else ())
+        return outs + (() if experts is None else (experts,))
 
     def _decode_in_place(self, params, tokens, arenas, pt16, pos, window,
                          n_sinks, want_density, unroll):
@@ -559,8 +564,9 @@ class DenseModel(ModelBase):
         of ``recompute``'s append mode.  miss_pos positions must map to
         bf16 pages already allocated in pt16 (padding positions map to
         the scratch page 0).  -> (arenas', hidden (1, M, d), density)."""
-        cs = arenas["k16"].shape[2]
-        cache = C.paged_cache_view(arenas, ("k", "v"), pt16, pt8,
+        leaves = self.kv_spec().seq_leaves
+        cs = arenas[leaves[0] + "16"].shape[2]
+        cache = C.paged_cache_view(arenas, leaves, pt16, pt8,
                                    quant_chunks, jnp.int32(0))
         new_cache, x, density = self.recompute(
             params, miss_tokens, miss_pos, cache, seq_len, window,
@@ -568,8 +574,8 @@ class DenseModel(ModelBase):
         pages = pt16[0, miss_pos // cs]
         offs = miss_pos % cs
         new_arenas = dict(arenas)
-        for n in ("k", "v"):
-            val = new_cache[n][:, 0, miss_pos]          # (L, M, KV, hd)
+        for n in leaves:
+            val = new_cache[n][:, 0, miss_pos]          # (L, M, ...)
             new_arenas[n + "16"] = arenas[n + "16"].at[
                 :, pages, offs].set(val)
         return new_arenas, x, density

@@ -1,18 +1,19 @@
-"""Mixture-of-experts FFN with grouped, capacity-based top-k dispatch.
+"""Mixture-of-experts FFN with dropless, per-token routing.
 
-GSPMD-friendly formulation: tokens are processed in groups (the group
-axis shards over "data"), experts dispatch via one-hot einsums (the
-expert axis shards over "model"), so XLA lowers the dispatch/combine to
-all-to-all-style collectives on the production mesh.
-
-Capacity C = ceil(group_size * top_k * capacity_factor / n_experts);
-overflowing tokens are dropped (their combine weight is zero) — the
-standard Mesh-TF/GShard discipline.  The auxiliary load-balancing loss
-follows Switch-Transformer: E * mean_e(frac_tokens_e * mean_prob_e).
+Every token is routed on its own: router scores over all experts
+(softmax), the greedy top-k of them, weighted by those scores as they
+are.  The (token, expert) pairs are sorted by expert and the experts
+run as one grouped matmul (``jax.lax.ragged_dot``: each expert
+multiplies exactly the rows routed to it), then each token sums its k
+expert outputs, weighted, in its own top-k order.  No token is ever
+dropped and nothing about a token's result depends on which other
+tokens share its batch, so prefill, decode and the Fig.-7 recompute of
+a context give the same latents whatever else the service is running.
+A fused always-on shared expert is added where the configuration has
+one.
 """
 from __future__ import annotations
 
-import math
 from typing import Dict, Tuple
 
 import jax
@@ -23,79 +24,51 @@ from repro.configs.base import MoEConfig
 Array = jax.Array
 
 
-def capacity(gs: int, moe: MoEConfig) -> int:
-    c = math.ceil(gs * moe.top_k * moe.capacity_factor / moe.n_experts)
-    return max(4, min(c, gs))
+def route(h: Array, router: Array, moe: MoEConfig) -> Tuple[Array, Array]:
+    """h (T, d) -> (weights (T, k) f32, expert ids (T, k) int32): the
+    top-k experts of each token by softmax router score, in descending
+    order."""
+    logits = h.astype(jnp.float32) @ router.astype(jnp.float32)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), moe.top_k)
+    return w, idx.astype(jnp.int32)
 
 
-def moe_dispatch(gates: Array, top_k: int, cap: int
-                 ) -> Tuple[Array, Array, Array]:
-    """gates: (G, gs, E) router probabilities.
-
-    Returns (dispatch (G,gs,E,C) bool-ish, combine (G,gs,E,C), aux_loss).
-    """
-    G, gs, E = gates.shape
-    remaining = gates
-    # per-expert running token count across the k iterations
-    count_so_far = jnp.zeros((G, 1, E), jnp.float32)
-    dispatch = jnp.zeros((G, gs, E, cap), jnp.float32)
-    combine = jnp.zeros((G, gs, E, cap), jnp.float32)
-    frac_routed = jnp.zeros((G, E), jnp.float32)
-    for _ in range(top_k):
-        idx = jnp.argmax(remaining, axis=-1)                     # (G, gs)
-        onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)       # (G, gs, E)
-        gate_k = jnp.sum(remaining * onehot, axis=-1)            # (G, gs)
-        remaining = remaining * (1.0 - onehot)
-        pos = jnp.cumsum(onehot, axis=1) - onehot + count_so_far  # (G, gs, E)
-        count_so_far = count_so_far + jnp.sum(onehot, axis=1, keepdims=True)
-        pos_in_e = jnp.sum(pos * onehot, axis=-1)                # (G, gs)
-        keep = (pos_in_e < cap).astype(jnp.float32)
-        slot = jax.nn.one_hot(pos_in_e.astype(jnp.int32), cap,
-                              dtype=jnp.float32)                 # (G, gs, C)
-        d_k = onehot[..., None] * slot[..., None, :] * keep[..., None, None]
-        dispatch = dispatch + d_k
-        combine = combine + d_k * gate_k[..., None, None]
-        frac_routed = frac_routed + jnp.mean(onehot, axis=1)
-    # Switch aux loss: E * sum_e frac_e * mean-prob_e (averaged over groups)
-    mean_prob = jnp.mean(gates, axis=1)                           # (G, E)
-    aux = E * jnp.mean(jnp.sum((frac_routed / top_k) * mean_prob, axis=-1))
-    return dispatch, combine, aux
-
-
-def moe_ffn(x: Array, p: Dict[str, Array], moe: MoEConfig
-            ) -> Tuple[Array, Dict[str, Array]]:
+def moe_ffn(x: Array, p: Dict[str, Array], moe: MoEConfig,
+            layer=None) -> Tuple[Array, Array]:
     """x: (..., d).  p: router (d,E), w_gate/w_up (E,d,fe), w_down (E,fe,d),
     optional s_gate/s_up (d,ds), s_down (ds,d) fused shared expert.
-    Returns (out (..., d), metrics)."""
+    With ``layer`` (a layer index, traced or not) the expert weights are
+    a stack of layers flattened to (n*E, ...) and this layer's experts
+    are groups ``layer*E ..``: the grouped matmul then takes the whole
+    stack as it lies, where XLA would copy a per-layer slice of it.
+    Returns (out (..., d), expert ids (T, k) int32 of the T = prod(...)
+    tokens)."""
     orig_shape = x.shape
     d = orig_shape[-1]
     xt = x.reshape(-1, d)
-    T = xt.shape[0]
-    gs = min(moe.group_size, T)
-    G = (T + gs - 1) // gs
-    pad = G * gs - T
-    if pad:
-        xt = jnp.pad(xt, ((0, pad), (0, 0)))
-    xg = xt.reshape(G, gs, d)
+    T, k = xt.shape[0], moe.top_k
+    w, idx = route(xt, p["router"], moe)
+    flat = idx.reshape(-1)                                   # (T*k,)
+    order = jnp.argsort(flat, stable=True)
+    if layer is not None:
+        flat = flat + layer * moe.n_experts
+    sizes = jnp.zeros((p["w_gate"].shape[0],), jnp.int32).at[flat].add(1)
+    xs = xt[order // k]                                      # (T*k, d)
 
-    logits = (xg.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)                      # (G, gs, E)
-    cap = capacity(gs, moe)
-    dispatch, combine, aux = moe_dispatch(gates, moe.top_k, cap)
-
-    expert_in = jnp.einsum("gsec,gsd->gecd", dispatch.astype(x.dtype), xg)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"])) \
-        * jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
-    out_e = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
-    y = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), out_e)
-    y = y.reshape(G * gs, d)
-    if pad:
-        y = y[:T]
+    def gmm(a, w):
+        return jax.lax.ragged_dot(a, w, sizes)
+    h = jax.nn.silu(gmm(xs, p["w_gate"])) * gmm(xs, p["w_up"])
+    out = gmm(h.astype(x.dtype), p["w_down"])
+    # back to (token, its j-th expert) order; the k-sum runs in that
+    # fixed order, so a row's result does not depend on the batch
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=order.dtype))
+    out = out[inv].reshape(T, k, d).astype(jnp.float32)
+    y = jnp.einsum("tkd,tk->td", out, w).astype(x.dtype)
     y = y.reshape(orig_shape)
     if "s_gate" in p:
         y = y + (jax.nn.silu(x @ p["s_gate"]) * (x @ p["s_up"])) @ p["s_down"]
-    metrics = {"moe_aux": aux}
-    return y, metrics
+    return y, idx
 
 
 def init_moe_params(key, d: int, moe: MoEConfig, dtype=jnp.bfloat16,

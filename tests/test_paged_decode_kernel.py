@@ -143,3 +143,61 @@ def test_rule_chooses_the_path(hd, quant, tpu, expect, monkeypatch):
     arenas = {"k16": jax.ShapeDtypeStruct((2, 5, CS, 2, hd), jnp.bfloat16)}
     pt8 = jnp.zeros((1, 4), jnp.int32) if quant else None
     assert C.paged_decode_in_place(arenas, pt8) is expect
+
+
+def test_latent_arena_takes_the_gather_path(monkeypatch):
+    """The leaf-generic round: with the rule's backend check steered to
+    the chip, a dense ``k``/``v`` arena still takes the kernel while
+    MLA's latent ``ckv``/``kpe`` arenas (no ``k16``) take the gather
+    path without error, write the round's new latents into their pages,
+    and return the routed layers' expert ids; the answer and the round
+    are those of the CPU's gather path.  The new latents the decode step
+    hands the round (``DecodeOut.new_kv``) are the rows it writes into
+    its cache at ``pos``."""
+    monkeypatch.setattr(C, "on_tpu", lambda: True)
+    dense = {"k16": jax.ShapeDtypeStruct((2, 5, CS, 2, 128), jnp.bfloat16)}
+    assert C.paged_decode_in_place(dense, None)
+    cfg = reduced(get_config("deepseek-v2-lite-16b"))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    m, L = cfg.mla, cfg.n_layers
+    rng = np.random.RandomState(0)
+    arenas = {"ckv16": jnp.asarray(rng.randn(L, POOL, CS, m.kv_lora_rank),
+                                   jnp.bfloat16),
+              "kpe16": jnp.asarray(rng.randn(L, POOL, CS,
+                                             m.qk_rope_head_dim),
+                                   jnp.bfloat16)}
+    assert not C.paged_decode_in_place(arenas, None)
+    pos = np.asarray([40, 17], np.int32)
+    pt = np.zeros((2, PAGES_A_ROW), np.int32)
+    pt[0, :3], pt[1, :2] = [5, 9, 2], [7, 11]
+    toks = jnp.asarray([[3], [4]], jnp.int32)
+    args = (toks, arenas, jnp.asarray(pt), None, None, jnp.asarray(pos))
+    a1, logits1, mass1, ids1 = _round(model, params, args, 0, 0, True,
+                                      monkeypatch)
+    a0, logits0, mass0, ids0 = _round(model, params, args, 0, 0, False,
+                                      monkeypatch)
+    np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits0))
+    np.testing.assert_array_equal(np.asarray(ids1), np.asarray(ids0))
+    n_moe = L - cfg.moe.first_dense
+    assert ids1.shape == (n_moe, 2, cfg.moe.top_k)
+    assert np.all((0 <= np.asarray(ids1))
+                  & (np.asarray(ids1) < cfg.moe.n_experts))
+    pages, offs = pt[[0, 1], pos // CS], pos % CS
+    for n in ("ckv16", "kpe16"):
+        keep = np.ones(arenas[n].shape[1:3], bool)
+        keep[pages, offs] = False
+        np.testing.assert_array_equal(np.asarray(a1[n])[:, keep],
+                                      np.asarray(arenas[n])[:, keep])
+        new = np.asarray(a1[n])[:, pages, offs]
+        assert not np.array_equal(new, np.asarray(arenas[n])[:, pages, offs])
+        np.testing.assert_array_equal(new, np.asarray(a0[n])[:, pages, offs])
+    view = C.paged_cache_view(arenas, ("ckv", "kpe"), jnp.asarray(pt), None,
+                              None, jnp.asarray(pos))
+    out = model.decode_step(params, toks, view)
+    for n in ("ckv", "kpe"):
+        written = np.asarray(out.cache[n])[:, [0, 1], pos]
+        np.testing.assert_array_equal(np.asarray(out.new_kv[n]), written)
+        np.testing.assert_array_equal(np.asarray(a0[n + "16"])[:, pages,
+                                                                offs],
+                                      written)

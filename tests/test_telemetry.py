@@ -254,10 +254,11 @@ def test_served_path_spans_and_counters(monkeypatch):
 @pytest.mark.parametrize("in_place", [True, False],
                          ids=["kernel", "gather"])
 def test_in_place_decode_counters(in_place, monkeypatch):
-    """``decode.inplace_rounds`` and ``decode.pages_attended`` count
-    what the rule chose: on the kernel's path (the rule's backend check
-    steered, the kernel interpreted) every round and the pages its rows'
-    attention spans; on the gather path nothing."""
+    """``decode.inplace_rounds`` counts what the rule chose: on the
+    kernel's path (the rule's backend check steered, the kernel
+    interpreted) every round, on the gather path nothing.
+    ``decode.pages_attended`` counts the pages the rows' attention spans
+    on either path."""
     import collections
     import dataclasses
 
@@ -292,13 +293,52 @@ def test_in_place_decode_counters(in_place, monkeypatch):
         counters = router.stats()["telemetry"]["counters"]
     assert [len(c) for c in calls] == [5] * 4
     assert rounds
+    assert counters["decode.pages_attended"] == sum(
+        p // 16 + 1 for pos in rounds for p in pos)
     if in_place:
         assert counters["decode.inplace_rounds"] == len(rounds)
-        assert counters["decode.pages_attended"] == sum(
-            p // 16 + 1 for pos in rounds for p in pos)
     else:
         assert "decode.inplace_rounds" not in counters
-        assert "decode.pages_attended" not in counters
+
+
+def test_moe_decode_counters(monkeypatch):
+    """A routed model's paged rounds (MLA + experts, on the pool's gather
+    path): ``moe.decode_rounds`` counts each round, ``moe.experts_hit``
+    adds the distinct experts its real rows route to in each routed
+    layer, read from the expert ids the program returns; pad rows of a
+    batch bucket are not counted."""
+    cfg, model, params = tiny_model("deepseek-v2-lite-16b")
+    sc = LLMSConfig(policy="llms", max_ctx_len=128, chunk_tokens=16,
+                    memory_budget=10_000_000, decode_batch=4,
+                    swap_dir=tempfile.mkdtemp())
+    svc = LLMService(model, params, sc)
+    assert svc.paged
+    router = ServiceRouter(svc, predict=True, slice_steps=2)
+    exe = svc.exe
+    rounds = []
+    fn = exe.paged_decode_fn
+
+    def recorded(*a):
+        out = fn(*a)
+        real = np.asarray(a[3])[:, 0] > 0            # pt16: page 0 is pad
+        n = int(real.sum())
+        assert real[:n].all()                        # real rows come first
+        rounds.append((n, np.asarray(out[-1])))
+        return out
+    monkeypatch.setattr(exe, "paged_decode_fn", recorded)
+    with svc:
+        serve(cfg, router, n_ctx=3, calls=2)
+        counters = router.stats()["telemetry"]["counters"]
+    n_moe, k = cfg.n_layers - cfg.moe.first_dense, cfg.moe.top_k
+    assert rounds and any(n < 4 for n, _ in rounds)    # a padded bucket
+    assert counters["moe.decode_rounds"] == len(rounds)
+    hits = [[len(set(ids[l, :n].ravel())) for l in range(n_moe)]
+            for n, ids in rounds]
+    assert counters["moe.experts_hit"] == sum(map(sum, hits))
+    for (n, ids), h in zip(rounds, hits):
+        assert ids.shape == (n_moe, 4, k)
+        assert all(1 <= x <= min(cfg.moe.n_experts, n * k) for x in h)
+    assert counters["decode.pages_attended"] > 0
 
 
 def test_reset_stats_resets_telemetry():

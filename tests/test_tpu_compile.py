@@ -131,3 +131,50 @@ def test_paged_decode_program_reads_pages_in_place(one_chip, monkeypatch):
     sizes = {math.prod(int(d) for d in dims.split(","))
              for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text)}
     assert view not in sizes
+
+
+def test_mla_decode_program_fits(one_chip):
+    """The executor's paged decode program at ``deepseek-v2-lite.decode``'s
+    shapes: every routed layer's three projections run as one ragged dot
+    over the group's whole flattened expert stack (sliced per layer, the
+    stacks were copied: 5.95 GB of temporaries, over the chip), and
+    arguments, outputs and temporaries fit the chip's 16 GB with room
+    for the runtime (12.95 GB)."""
+    import json
+    from pathlib import Path
+
+    from chipbench.configs import mla_moe
+    from repro.configs.base import ModelConfig
+    from repro.core.executor import paged_decode_program
+    from repro.models.registry import build_model
+
+    root = Path(__file__).resolve().parent.parent / "chipbench"
+    cfg = json.loads((root / "configs" / "deepseek-v2-lite.json").read_text())
+    sv = json.loads((root / "traffic" / "decode_moe.json").read_text()
+                    )["service"]
+    model = build_model(ModelConfig(**mla_moe.program_config(cfg)))
+    mc = model.cfg
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    cs, B, P = T, sv["decode_batch"], sv["pool_pages_16"]
+    C = 2 * sv["max_ctx"] // cs     # window plus the largest prompt bucket
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    arenas = {"ckv16": s((mc.n_layers, P, cs, mc.mla.kv_lora_rank),
+                         jnp.bfloat16),
+              "kpe16": s((mc.n_layers, P, cs, mc.mla.qk_rope_head_dim),
+                         jnp.bfloat16)}
+    compiled = paged_decode_program(model).lower(
+        params, s((B, 1), jnp.int32), arenas, s((B, C), jnp.int32), None,
+        None, s((B,), jnp.int32)).compile()
+    text = compiled.as_text()
+    n_moe = mc.n_layers - mc.moe.first_dense
+    stack = rf"bf16\[{n_moe * mc.moe.n_experts},"
+    dots = re.findall(r"%ragged-dot-none[.\d]* = .*", text)
+    assert len(dots) == 3 * n_moe
+    assert all(re.search(stack, d) for d in dots)
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total <= 13.5e9, total
