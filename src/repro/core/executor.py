@@ -109,14 +109,101 @@ def _feed_fetch(layer):
     return _ACTIVE_FEED.fetch(layer)
 
 
-def paged_decode_program(model, window: int = 0, n_sinks: int = 0):
-    """The jitted paged decode round, density on.  The layer unroll
-    mirrors the old batched-decode entry: XLA CPU's rolled scan shuffles
-    the gathered multi-row cache every layer and dominates the step."""
+# The programs that write the page arenas donate them (their ``arenas``
+# argument): the page or token rows they write land in the arena's own
+# buffer instead of in a fresh copy of the whole arena.  A caller's
+# handle to the arenas it passed is dead after the dispatch; every
+# caller rebinds the result (``pool.arenas = exe.<entry>(pool.arenas,
+# ...)``) and none may keep an older handle.  The one exception is the
+# decode round on the gather path (``decode_donates``).
+
+def decode_donates(model, quant_resident: bool = False) -> bool:
+    """Whether the paged decode round donates its arenas: where it reads
+    its pages in place (``paged_decode_in_place``), so that donated it
+    writes the round's K/V rows into them and copies no arena.  On the
+    gather path the compiler copies and relayouts the arenas into the
+    dense view whether they are donated or not, and holds more
+    temporaries when they are (DeepSeek-V2-Lite's round compiled for a
+    v5e: 1.22 -> 1.83 GB), so that round keeps its arguments."""
+    spec = model.kv_spec()
+    shapes = {n + "16": jax.ShapeDtypeStruct(spec.leaf_dims[n], jnp.bfloat16)
+              for n in spec.seq_leaves}
+    return not quant_resident and paged_decode_in_place(shapes, None)
+
+
+def paged_decode_program(model, window: int = 0, n_sinks: int = 0,
+                         quant_resident: bool = False):
+    """The jitted paged decode round, density on, its arenas (argument
+    2) donated where ``decode_donates``.  The layer unroll mirrors the
+    old batched-decode entry: XLA CPU's rolled scan shuffles the
+    gathered multi-row cache every layer and dominates the step."""
     L = model.cfg.n_layers
     return jax.jit(functools.partial(
         model.decode_paged, want_density=True, unroll=L if L <= 48 else 1,
-        window=window, n_sinks=n_sinks))
+        window=window, n_sinks=n_sinks),
+        donate_argnums=2 if decode_donates(model, quant_resident) else ())
+
+
+def paged_extend_program(model, window: int = 0, n_sinks: int = 0):
+    """The jitted paged prefill-append, density on, arenas (argument 3)
+    donated."""
+    return jax.jit(functools.partial(
+        model.extend_paged, want_density=True, window=window,
+        n_sinks=n_sinks), donate_argnums=3)
+
+
+def page_programs(leaves: Tuple[str, ...], leaf_dims, cs: int,
+                  n_layers: int):
+    """The jitted page writes (``admit16``, ``admit8``, ``zero16``,
+    arenas donated) and page read (``read16``) of a pool whose arenas
+    are ``(n_layers, P, cs, *leaf_dims[n])`` per leaf ``n``."""
+    dims, nl = dict(leaf_dims), n_layers
+
+    # admission converts the chunk-file block layout (cs, L*prod(dims))
+    # used by the codec/payload paths into the page layout (L, cs,
+    # *dims) inside the jit, so host code hands over exactly the
+    # payload blocks.
+    def admit16(arenas, page, blocks):
+        out = dict(arenas)
+        for n in leaves:
+            t = blocks[n].reshape(cs, nl, 1, *dims[n])[:, :, 0]
+            t = jnp.moveaxis(t, 0, 1)
+            out[n + "16"] = arenas[n + "16"].at[:, page].set(
+                t.astype(arenas[n + "16"].dtype))
+        return out
+
+    def admit8(arenas, page, codes, scales):
+        out = dict(arenas)
+        for n in leaves:
+            t = codes[n].reshape(cs, nl, 1, *dims[n])[:, :, 0]
+            out[n + "8"] = arenas[n + "8"].at[:, page].set(
+                jnp.moveaxis(t, 0, 1))
+            s = scales[n].reshape(cs, nl, *dims[n][:-1])
+            out[n + "8s"] = arenas[n + "8s"].at[:, page].set(
+                jnp.moveaxis(s, 0, 1))
+        return out
+
+    def read16(arenas, page):
+        return {n: jnp.moveaxis(
+            arenas[n + "16"][:, page], 0, 1).reshape(cs, -1)
+            for n in leaves}
+
+    # fresh tail pages must start as zeros: the slot path's never-written
+    # positions are exactly zero (fresh_cache is the shared zero cache),
+    # and unwritten-but-attended positions (e.g. a call's final emitted
+    # token) must encode identically on both paths
+    def zero16(arenas, page):
+        out = dict(arenas)
+        for n in leaves:
+            a = arenas[n + "16"]
+            out[n + "16"] = a.at[:, page].set(
+                jnp.zeros(a.shape[:1] + a.shape[2:], a.dtype))
+        return out
+
+    return {"admit16": jax.jit(admit16, donate_argnums=0),
+            "admit8": jax.jit(admit8, donate_argnums=0),
+            "read16": jax.jit(read16),
+            "zero16": jax.jit(zero16, donate_argnums=0)}
 
 
 def _pow2_buckets(lo: int, hi: int) -> List[int]:
@@ -135,7 +222,11 @@ class ModelExecutor:
     ``admit8``/``zero16``/``read16``) dispatch their programs inside an
     ``exe.<entry>`` span of ``telemetry``, so a profiler trace shows
     which entry issued each device program; the wait for the results
-    and their copy to the host fall outside it."""
+    and their copy to the host fall outside it.  The writing entries
+    (all but ``read16``, and ``paged_decode`` only where
+    ``decode_donates``) donate the arenas they are passed and count
+    whether the program took them (``exe.arena_donated``) or wrote a
+    copy (``exe.arena_copied``)."""
 
     def __init__(self, model, params, cfg,
                  telemetry: Optional[Telemetry] = None):
@@ -248,63 +339,18 @@ class ModelExecutor:
             pcached = _jit_cache_get(pk)
             if pcached is None:
                 cw = dict(window=cfg.window, n_sinks=cfg.n_sinks)
-                leaves = tuple(self.codec.leaves)
-                dims = dict(self.leaf_dims)
-                cs, nl = self.cs, self.n_layers
-
-                # admission converts the chunk-file block layout
-                # (cs, L*prod(dims)) used by the codec/payload paths
-                # into the page layout (L, cs, *dims) inside the jit, so
-                # host code hands over exactly the payload blocks.
-                def admit16(arenas, page, blocks):
-                    out = dict(arenas)
-                    for n in leaves:
-                        t = blocks[n].reshape(cs, nl, 1, *dims[n])[:, :, 0]
-                        t = jnp.moveaxis(t, 0, 1)
-                        out[n + "16"] = arenas[n + "16"].at[:, page].set(
-                            t.astype(arenas[n + "16"].dtype))
-                    return out
-
-                def admit8(arenas, page, codes, scales):
-                    out = dict(arenas)
-                    for n in leaves:
-                        t = codes[n].reshape(cs, nl, 1, *dims[n])[:, :, 0]
-                        out[n + "8"] = arenas[n + "8"].at[:, page].set(
-                            jnp.moveaxis(t, 0, 1))
-                        s = scales[n].reshape(cs, nl, *dims[n][:-1])
-                        out[n + "8s"] = arenas[n + "8s"].at[:, page].set(
-                            jnp.moveaxis(s, 0, 1))
-                    return out
-
-                def read16(arenas, page):
-                    return {n: jnp.moveaxis(
-                        arenas[n + "16"][:, page], 0, 1).reshape(cs, -1)
-                        for n in leaves}
-
-                # fresh tail pages must start as zeros: the slot path's
-                # never-written positions are exactly zero (fresh_cache
-                # is the shared zero cache), and unwritten-but-attended
-                # positions (e.g. a call's final emitted token) must
-                # encode identically on both paths
-                def zero16(arenas, page):
-                    out = dict(arenas)
-                    for n in leaves:
-                        a = arenas[n + "16"]
-                        out[n + "16"] = a.at[:, page].set(
-                            jnp.zeros(a.shape[:1] + a.shape[2:], a.dtype))
-                    return out
-
+                qr = self.quant_resident
                 pcached = {
-                    "decode": paged_decode_program(model, **cw),
-                    "extend": jax.jit(functools.partial(
-                        model.extend_paged, want_density=True, **cw)),
-                    "admit16": jax.jit(admit16),
-                    "admit8": jax.jit(admit8),
-                    "read16": jax.jit(read16),
-                    "zero16": jax.jit(zero16),
+                    "decode": paged_decode_program(model, quant_resident=qr,
+                                                   **cw),
+                    "decode_donates": decode_donates(model, qr),
+                    "extend": paged_extend_program(model, **cw),
+                    **page_programs(tuple(self.codec.leaves),
+                                    self.leaf_dims, self.cs, self.n_layers),
                 }
                 _jit_cache_put(pk, pcached)
             self.paged_decode_fn = pcached["decode"]
+            self.decode_donates = pcached["decode_donates"]
             self.paged_extend_fn = pcached["extend"]
             self.admit16_fn = pcached["admit16"]
             self.admit8_fn = pcached["admit8"]
@@ -396,15 +442,16 @@ class ModelExecutor:
         pos_b = self.bucket_pad(pos, self.pad_slot)
         toks_b = self.bucket_pad(np.asarray(prompt, np.int32), 0)
         with self.tel.span("exe.paged_extend"):
-            arenas, hidden, dens = self.paged_extend_fn(
+            out, hidden, dens = self.paged_extend_fn(
                 self.params, jnp.asarray(toks_b)[None], jnp.asarray(pos_b),
                 arenas, jnp.asarray(pt16),
                 None if pt8 is None else jnp.asarray(pt8),
                 None if qmask is None else jnp.asarray(qmask),
                 jnp.int32(n0 + M))
+            self._count_donation(arenas)
             logits = self.logits_fn(self.params, hidden[:, M - 1])
             dens = dens[0]
-        return arenas, np.asarray(logits)[0], np.asarray(dens, np.float64)
+        return out, np.asarray(logits)[0], np.asarray(dens, np.float64)
 
     def paged_decode(self, arenas, toks: Sequence[int], pos: Sequence[int],
                      pt16, pt8, qmask):
@@ -441,40 +488,59 @@ class ModelExecutor:
         self.tel.count("decode.pages_attended",
                        int(sum(p // self.cs + 1 for p in pos)))
         with self.tel.span("exe.paged_decode"):
-            arenas, *outs = self.paged_decode_fn(
+            out, *outs = self.paged_decode_fn(
                 self.params, jnp.asarray(toks_b), arenas,
                 jnp.asarray(pt16_b),
                 None if pt8_b is None else jnp.asarray(pt8_b),
                 None if qmask_b is None else jnp.asarray(qmask_b),
                 jnp.asarray(pos_b))
+            if self.decode_donates:
+                self._count_donation(arenas)
         logits, mass, *experts = jax.device_get(outs)
         if experts:
             self.tel.count("moe.decode_rounds")
             self.tel.count("moe.experts_hit", sum(
                 len(np.unique(ids[:n])) for ids in experts[0]))
-        return (arenas, np.asarray(logits)[:n],
+        return (out, np.asarray(logits)[:n],
                 np.asarray(mass, np.float64)[:n])
 
+    def _count_donation(self, arenas):
+        """After a writing program's dispatch: ``exe.arena_donated`` if
+        it took the buffers of the ``arenas`` it was passed (their first
+        leaf is deleted), else ``exe.arena_copied`` (the runtime refused
+        the alias and the program wrote a whole new arena)."""
+        leaf = next(iter(arenas.values()))
+        self.tel.count("exe.arena_donated" if leaf.is_deleted()
+                       else "exe.arena_copied")
+
     # page writes and reads of the pool (page 0 is the scratch page);
-    # each admission or zeroing rewrites the whole arena
+    # a write donates the arenas, so it moves one page's bytes and
+    # the passed ``arenas`` are dead afterwards
     def admit16(self, arenas, page: int, blocks):
-        """Write (cs, F) bf16 ``blocks`` into bf16 page ``page``."""
+        """Write (cs, F) bf16 ``blocks`` into bf16 page ``page`` of the
+        donated ``arenas``; -> the arenas, written in place."""
         with self.tel.span("exe.admit16"):
             out = self.admit16_fn(arenas, page, blocks)
+            self._count_donation(arenas)
         self.tel.count("pages_admitted16")
         return out
 
     def admit8(self, arenas, page: int, codes, scales):
         """Write decode-grid int8 ``codes`` + ``scales`` into quant page
-        ``page``."""
+        ``page`` of the donated ``arenas``; -> the arenas, written in
+        place."""
         with self.tel.span("exe.admit8"):
             out = self.admit8_fn(arenas, page, codes, scales)
+            self._count_donation(arenas)
         self.tel.count("pages_admitted8")
         return out
 
     def zero16(self, arenas, page: int):
+        """Zero bf16 page ``page`` of the donated ``arenas``; -> the
+        arenas, written in place."""
         with self.tel.span("exe.zero16"):
             out = self.zero16_fn(arenas, page)
+            self._count_donation(arenas)
         self.tel.count("pages_zeroed")
         return out
 

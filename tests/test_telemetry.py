@@ -251,14 +251,10 @@ def test_served_path_spans_and_counters(monkeypatch):
             (name, parents)
 
 
-@pytest.mark.parametrize("in_place", [True, False],
-                         ids=["kernel", "gather"])
-def test_in_place_decode_counters(in_place, monkeypatch):
-    """``decode.inplace_rounds`` counts what the rule chose: on the
-    kernel's path (the rule's backend check steered, the kernel
-    interpreted) every round, on the gather path nothing.
-    ``decode.pages_attended`` counts the pages the rows' attention spans
-    on either path."""
+def make_served_hd128(monkeypatch, in_place, budget=10_000_000):
+    """A small paged service of a 2-layer qwen2.5-14b at ``hd`` 128, with
+    the rule's backend check steered to ``in_place`` (the kernel
+    interpreted where it is taken)."""
     import collections
     import dataclasses
 
@@ -276,10 +272,21 @@ def test_in_place_decode_counters(in_place, monkeypatch):
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     sc = LLMSConfig(policy="llms", max_ctx_len=128, chunk_tokens=16,
-                    memory_budget=10_000_000, decode_batch=2,
+                    memory_budget=budget, decode_batch=2,
                     swap_dir=tempfile.mkdtemp())
     svc = LLMService(model, params, sc)
-    router = ServiceRouter(svc, predict=True, slice_steps=2)
+    return cfg, svc, ServiceRouter(svc, predict=True, slice_steps=2)
+
+
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel", "gather"])
+def test_in_place_decode_counters(in_place, monkeypatch):
+    """``decode.inplace_rounds`` counts what the rule chose: on the
+    kernel's path (the rule's backend check steered, the kernel
+    interpreted) every round, on the gather path nothing.
+    ``decode.pages_attended`` counts the pages the rows' attention spans
+    on either path."""
+    cfg, svc, router = make_served_hd128(monkeypatch, in_place)
     exe = svc.exe
     rounds = []
     decode = exe.paged_decode
@@ -299,6 +306,41 @@ def test_in_place_decode_counters(in_place, monkeypatch):
         assert counters["decode.inplace_rounds"] == len(rounds)
     else:
         assert "decode.inplace_rounds" not in counters
+
+
+@pytest.mark.filterwarnings("error:Some donated buffers were not usable")
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["kernel", "gather"])
+def test_arena_writes_donate_the_arenas(in_place, monkeypatch):
+    """On a served run over the pool every program that writes the page
+    arenas takes the buffers it is passed: the arena handle an
+    ``admit16``, ``zero16`` or ``paged_extend`` was given is dead
+    afterwards, as is a ``paged_decode``'s on the kernel's path (the
+    gather path's round keeps its arguments), and ``exe.arena_donated``
+    counts every such write, with no ``exe.arena_copied`` (the runtime
+    never refused the alias: its warning is an error here)."""
+    cfg, svc, router = make_served_hd128(monkeypatch, in_place,
+                                         budget=8_000)
+    exe = svc.exe
+    assert exe.decode_donates is in_place
+    passed = {n: [] for n in ("admit16", "zero16", "paged_extend",
+                              "paged_decode")}
+    for name, handles in passed.items():
+        def kept(arenas, *a, _fn=getattr(exe, name), _handles=handles):
+            _handles.append(next(iter(arenas.values())))
+            return _fn(arenas, *a)
+        monkeypatch.setattr(exe, name, kept)
+    with svc:
+        serve(cfg, router, n_ctx=3, calls=2)
+        counters = router.stats()["telemetry"]["counters"]
+    assert all(passed.values())
+    for name, handles in passed.items():
+        dead = in_place or name != "paged_decode"
+        assert all(h.is_deleted() is dead for h in handles), name
+    writes = sum(len(h) for n, h in passed.items()
+                 if in_place or n != "paged_decode")
+    assert counters["exe.arena_donated"] == writes > 0
+    assert "exe.arena_copied" not in counters
 
 
 def test_moe_decode_counters(monkeypatch):

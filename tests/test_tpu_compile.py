@@ -93,44 +93,117 @@ def test_decode_qattn_compiles(one_chip):
                            *_decode_shapes(one_chip, 4, 2048, mixed=False))
 
 
+# cell -> (configuration file, traffic file, the configuration's module
+# under chipbench/configs)
+CELLS = {"smollm-360m.switch": ("smollm-360m", "switch", "dense"),
+         "qwen2.5-14b.decode": ("qwen2.5-14b", "decode", "dense"),
+         "deepseek-v2-lite.decode": ("deepseek-v2-lite", "decode_moe",
+                                     "mla_moe")}
+
+
+def _cell(cell, sharding):
+    """The model of a benchmark cell at its published widths, shape
+    specs of its parameters and page arenas, and its page-table width:
+    -> (model, params, arenas, service settings, C, spec maker)."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    from repro.configs.base import ModelConfig
+    from repro.models.registry import build_model
+
+    cfg_name, traffic, module = CELLS[cell]
+    root = Path(__file__).resolve().parent.parent / "chipbench"
+    cfg = json.loads((root / "configs" / f"{cfg_name}.json").read_text())
+    sv = json.loads((root / "traffic" / f"{traffic}.json").read_text()
+                    )["service"]
+    fam = importlib.import_module(f"chipbench.configs.{module}")
+    model = build_model(ModelConfig(**fam.program_config(cfg)))
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = model.kv_spec()
+    arenas = {n + "16": s((model.cfg.n_layers, sv["pool_pages_16"], T,
+                           *spec.leaf_dims[n]), jnp.bfloat16)
+              for n in spec.seq_leaves}
+    C = 2 * sv["max_ctx"] // T      # window plus the largest prompt bucket
+    return model, params, arenas, sv, C, s
+
+
+def _arena_copies(text, arenas):
+    """``copy`` ops in a compiled program whose result has an arena's
+    shape: each one rewrites a whole arena."""
+    shapes = "|".join(",".join(map(str, a.shape)) for a in arenas.values())
+    return re.findall(rf"= bf16\[(?:{shapes})\]\{{[^}}]*\}} copy\(", text)
+
+
 def test_paged_decode_program_reads_pages_in_place(one_chip, monkeypatch):
     """The executor's paged decode program at ``qwen2.5-14b.decode``'s
     shapes takes the in-place kernel: no buffer of the dense ``(L, B, S,
     KV, hd)`` view anywhere in the program, and its temporaries stay
-    small (2.35 GB with the view)."""
-    import json
-    from pathlib import Path
-
-    from chipbench.configs import dense
-    from repro.configs.base import ModelConfig
-    from repro.core.executor import paged_decode_program
+    small (2.35 GB with the view).  It donates its arenas and writes the
+    round's rows into them: the arenas alias its output, and no copy of
+    an arena is left in it (two without donation)."""
+    from repro.core.executor import decode_donates, paged_decode_program
     from repro.models import common
-    from repro.models.registry import build_model
 
-    root = Path(__file__).resolve().parent.parent / "chipbench"
-    cfg = json.loads((root / "configs" / "qwen2.5-14b.json").read_text())
-    sv = json.loads((root / "traffic" / "decode.json").read_text())["service"]
-    model = build_model(ModelConfig(**dense.program_config(cfg)))
-    mc = model.cfg
     monkeypatch.setattr(common, "on_tpu", lambda: True)
-
-    def s(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    cs, B, P = T, sv["decode_batch"], sv["pool_pages_16"]
-    C = 2 * sv["max_ctx"] // cs     # window plus the largest prompt bucket
-    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
-                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    arena = s((mc.n_layers, P, cs, mc.n_kv_heads, mc.head_dim), jnp.bfloat16)
+    model, params, arenas, sv, C, s = _cell("qwen2.5-14b.decode", one_chip)
+    mc, B = model.cfg, sv["decode_batch"]
+    assert decode_donates(model)
     compiled = paged_decode_program(model).lower(
-        params, s((B, 1), jnp.int32), {"k16": arena, "v16": arena},
+        params, s((B, 1), jnp.int32), arenas,
         s((B, C), jnp.int32), None, None, s((B,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert compiled.memory_analysis().temp_size_in_bytes <= 0.25e9
-    view = mc.n_layers * B * C * cs * mc.n_kv_heads * mc.head_dim
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes <= 0.25e9
+    view = mc.n_layers * B * C * T * mc.n_kv_heads * mc.head_dim
     sizes = {math.prod(int(d) for d in dims.split(","))
              for dims in re.findall(r"\b[a-z]+\d*\[([\d,]+)\]", text)}
     assert view not in sizes
+    assert ma.alias_size_in_bytes >= sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in arenas.values())
+    assert not _arena_copies(text, arenas)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_arena_writers_donate_the_arenas(one_chip, monkeypatch, cell):
+    """At each cell's shapes the executor's page writes (``admit16``,
+    ``zero16``) and paged prefill-append donate the page arenas: the
+    arenas alias each program's output, and a page write copies no
+    arena (two whole-arena copies each without donation).  The decode
+    round donates where it reads its pages in place (the dense cell);
+    the gather path of the others keeps its arguments."""
+    from repro.core.executor import (decode_donates, page_programs,
+                                     paged_extend_program)
+    from repro.models import common
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    model, params, arenas, sv, C, s = _cell(cell, one_chip)
+    spec, L = model.kv_spec(), model.cfg.n_layers
+    assert decode_donates(model) is (cell == "qwen2.5-14b.decode")
+    arena_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                      for a in arenas.values())
+    progs = page_programs(spec.seq_leaves, spec.leaf_dims, T, L)
+    page = s((), jnp.int32)
+    blocks = {n: s((T, L * math.prod(spec.leaf_dims[n])), jnp.bfloat16)
+              for n in spec.seq_leaves}
+    M = 256                                         # one prompt bucket
+    compiled = {
+        "admit16": progs["admit16"].lower(arenas, page, blocks),
+        "zero16": progs["zero16"].lower(arenas, page),
+        "extend": paged_extend_program(model).lower(
+            params, s((1, M), jnp.int32), s((M,), jnp.int32), arenas,
+            s((1, C), jnp.int32), None, None, s((), jnp.int32)),
+    }
+    for name, lowered in compiled.items():
+        c = lowered.compile()
+        assert c.memory_analysis().alias_size_in_bytes >= arena_bytes, name
+        if name != "extend":
+            assert not _arena_copies(c.as_text(), arenas), name
 
 
 def test_mla_decode_program_fits(one_chip):
@@ -140,31 +213,11 @@ def test_mla_decode_program_fits(one_chip):
     stacks were copied: 5.95 GB of temporaries, over the chip), and
     arguments, outputs and temporaries fit the chip's 16 GB with room
     for the runtime (12.95 GB)."""
-    import json
-    from pathlib import Path
-
-    from chipbench.configs import mla_moe
-    from repro.configs.base import ModelConfig
     from repro.core.executor import paged_decode_program
-    from repro.models.registry import build_model
 
-    root = Path(__file__).resolve().parent.parent / "chipbench"
-    cfg = json.loads((root / "configs" / "deepseek-v2-lite.json").read_text())
-    sv = json.loads((root / "traffic" / "decode_moe.json").read_text()
-                    )["service"]
-    model = build_model(ModelConfig(**mla_moe.program_config(cfg)))
-    mc = model.cfg
-
-    def s(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    cs, B, P = T, sv["decode_batch"], sv["pool_pages_16"]
-    C = 2 * sv["max_ctx"] // cs     # window plus the largest prompt bucket
-    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
-                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    arenas = {"ckv16": s((mc.n_layers, P, cs, mc.mla.kv_lora_rank),
-                         jnp.bfloat16),
-              "kpe16": s((mc.n_layers, P, cs, mc.mla.qk_rope_head_dim),
-                         jnp.bfloat16)}
+    model, params, arenas, sv, C, s = _cell("deepseek-v2-lite.decode",
+                                            one_chip)
+    mc, B = model.cfg, sv["decode_batch"]
     compiled = paged_decode_program(model).lower(
         params, s((B, 1), jnp.int32), arenas, s((B, C), jnp.int32), None,
         None, s((B,), jnp.int32)).compile()
